@@ -7,6 +7,8 @@ import sys
 import time
 import traceback
 
+from repro.accel.engine import use_compile_cache
+
 from . import (bench_hotpath, bench_kernels, bench_scenarios, fig10_overhead,
                fig11_breakdown, fig12_numjobs, fig13_tiers, fig14_fairness,
                table1_workloads, table2_demand_percentiles,
@@ -29,6 +31,7 @@ ALL = [
 
 
 def main() -> None:
+    use_compile_cache()
     only = sys.argv[1:] or None
     print("name,us_per_call,derived")
     failures = []

@@ -26,16 +26,20 @@ vectorized.  The result is bit-identical to the sequential scan; a
 sequential reference (:func:`match_chunk_seq`) backs the property tests and
 serves as a safety net on non-convergence.
 
-Backends: ``numpy`` (default — the fast path on CPU simulators), ``jax``
-(jitted ``lax.while_loop`` on padded shapes, the TPU-resident path), and the
-``jax`` backend with ``use_kernel=True`` routing the inner masked first-fit
-through the Pallas kernel (:mod:`repro.accel.kernels.schedule_match`).
+Backends: ``numpy`` (the fixed point in NumPy), ``jax`` (jitted
+``lax.while_loop`` on padded shapes), and the ``jax`` backend with
+``use_kernel=True`` routing the inner masked first-fit through the Pallas
+kernel (:mod:`repro.accel.kernels.schedule_match`).  The platform chooses
+(:func:`platform_backend`): ``jax`` with the kernel where JAX runs on a
+TPU, ``numpy`` on the CPU.  Explicit ``backend=``/``use_kernel=`` arguments
+exist for tests.
 """
 from __future__ import annotations
 
 import os
 import time
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Optional, Tuple
 
 import numpy as np
@@ -46,7 +50,7 @@ from ..obs import trace as _obstrace
 from .state import MatchState
 
 __all__ = ["ArrayMatchEngine", "MatchResult", "SEG_ROWS", "match_chunk",
-           "match_chunk_seq"]
+           "match_chunk_seq", "platform_backend", "use_compile_cache"]
 
 # Upper bound on check-in rows per match call.  Prefix consistency makes
 # slicing exact (a device's outcome depends only on earlier devices), and the
@@ -242,6 +246,34 @@ def match_chunk_jax(atom_ids: np.ndarray, speeds: np.ndarray,
     return out
 
 
+# JAX's persistent compilation cache, when JAX_COMPILATION_CACHE_DIR does not
+# place it: a fixed path inside the checkout (the path is part of the
+# cache's key, so a moving directory never hits)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads
+    it itself), else :data:`COMPILE_CACHE_DIR`.  Entry points call this;
+    importing the package never does."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
+
+
+def platform_backend() -> Tuple[str, bool]:
+    """``(backend, use_kernel)`` for the platform JAX runs on: the jitted
+    fixed point with the Pallas kernel on a TPU, NumPy on the CPU."""
+    import jax
+    if jax.default_backend() == "tpu":
+        return "jax", True
+    return "numpy", False
+
+
 # --------------------------------------------------------------------------- #
 # Simulator-facing driver
 # --------------------------------------------------------------------------- #
@@ -259,16 +291,22 @@ class ArrayMatchEngine:
     * grants the simulator applies are mirrored via ``state.consume``.
     """
 
-    def __init__(self, backend: str = "numpy", use_kernel: bool = False,
-                 kcap: int = 32, replan_budget_s: Optional[float] = None):
+    def __init__(self, backend: Optional[str] = None,
+                 use_kernel: Optional[bool] = None, kcap: int = 32,
+                 replan_budget_s: Optional[float] = None):
+        if backend is None:
+            backend, kernel = platform_backend()
+            if use_kernel is None:
+                use_kernel = kernel
         if backend not in ("numpy", "jax"):
             raise ValueError(f"unknown accel backend {backend!r}")
         self.backend = backend
-        self.use_kernel = use_kernel
+        self.use_kernel = bool(use_kernel)
         self.kcap = kcap                # adaptive candidate cap, sticky upward
         self.state: Optional[MatchState] = None
         self.rebuilds = 0
         self.segments = 0
+        self.backend_calls = 0          # segments matched by the backend
         self.expansions = 0
         # ---- mirror deltas ----
         # On a token change the engine asks the scheduler for the dirty-atom
@@ -458,52 +496,22 @@ class ArrayMatchEngine:
 
     def _match_guarded(self, sub_ids: np.ndarray, sub_speeds: np.ndarray,
                        st: MatchState) -> MatchResult:
-        """Vectorized match with divergence guards: non-finite inputs,
-        backend exceptions, or an implausible result all degrade the segment
-        to the sequential oracle (bit-identical semantics) with a counter —
-        never an exception out of the drain loop."""
+        """Vectorized match, except for non-finite speeds: those segments
+        go to the sequential oracle (bit-identical semantics), counted.  A
+        backend failure propagates — it is never served from the host."""
         if not bool(np.isfinite(sub_speeds).all()):
             # corrupted speed readings: the sequential scan's comparisons
             # reject NaN/inf rows exactly like the scalar engine's checkin
             # does, while backend kernels aren't audited for non-finite
             # inputs — serve the whole segment scalar-side
-            return self._degrade("nonfinite", sub_ids, sub_speeds, st)
-        try:
-            if self.backend == "jax":
-                res = match_chunk_jax(sub_ids, sub_speeds, st,
-                                      use_kernel=self.use_kernel)
-            else:
-                res = match_chunk(sub_ids, sub_speeds, st)
-        except Exception:
-            return self._degrade("exception", sub_ids, sub_speeds, st)
-        if not self._plausible(res, len(sub_ids), st):
-            return self._degrade("implausible", sub_ids, sub_speeds, st)
-        return res
-
-    def _degrade(self, reason: str, sub_ids: np.ndarray,
-                 sub_speeds: np.ndarray, st: MatchState) -> MatchResult:
-        """Serve one segment through the sequential oracle, counted + traced."""
-        self.degraded_segments += 1
-        tr = _obstrace.TRACER
-        if tr.enabled:
-            tr.instant("accel.degraded", cat="accel", reason=reason,
-                       rows=len(sub_ids))
-        return match_chunk_seq(sub_ids, sub_speeds, st)
-
-    @staticmethod
-    def _plausible(res: MatchResult, m: int, st: MatchState) -> bool:
-        """Cheap invariants every correct match satisfies: shapes, choice
-        range, granted ⇒ chosen, per-request grants within capacity."""
-        ch, gr = res.choice, res.granted
-        if ch.shape != (m,) or gr.shape != (m,):
-            return False
-        R = len(st.remaining)
-        if m and (int(ch.min()) < -1 or int(ch.max()) >= R):
-            return False
-        if bool((gr & (ch < 0)).any()):
-            return False
-        if bool(gr.any()):
-            counts = np.bincount(ch[gr], minlength=R)
-            if bool((counts > st.remaining).any()):
-                return False
-        return True
+            self.degraded_segments += 1
+            tr = _obstrace.TRACER
+            if tr.enabled:
+                tr.instant("accel.degraded", cat="accel", reason="nonfinite",
+                           rows=len(sub_ids))
+            return match_chunk_seq(sub_ids, sub_speeds, st)
+        self.backend_calls += 1
+        if self.backend == "jax":
+            return match_chunk_jax(sub_ids, sub_speeds, st,
+                                   use_kernel=self.use_kernel)
+        return match_chunk(sub_ids, sub_speeds, st)

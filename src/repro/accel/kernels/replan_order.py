@@ -38,7 +38,8 @@ def _kernel(seg_r, key_r, tie_r, seg_c, key_c, tie_c, o_ref):
     same = seg_c[...] == seg_r[...]
     less = (key_c[...] < key_r[...]) | ((key_c[...] == key_r[...])
                                         & (tie_c[...] < tie_r[...]))
-    o_ref[...] = jnp.sum((same & less).astype(jnp.int32), axis=1)
+    o_ref[...] = jnp.sum((same & less).astype(jnp.int32), axis=1,
+                         keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -76,12 +77,14 @@ def segmented_rank(seg_ids: jax.Array, keys: jax.Array, ties: jax.Array,
             pl.BlockSpec((1, np_), lambda ni: (0, 0)),
             pl.BlockSpec((1, np_), lambda ni: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda ni: (ni,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.int32),
+        # (bn, 1) column blocks, as in schedule_match: Mosaic refuses a
+        # 1-D output block on a multi-block grid
+        out_specs=pl.BlockSpec((bn, 1), lambda ni: (ni, 0)),
+        out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.int32),
         interpret=interpret,
     )(seg[:, None], key[:, None], tie[:, None],
       seg[None, :], key[None, :], tie[None, :])
-    return out[:n]
+    return out[:n, 0]
 
 
 def segmented_order(seg_ids: jax.Array, keys: jax.Array, ties: jax.Array,

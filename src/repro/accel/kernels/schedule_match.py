@@ -11,11 +11,12 @@ The candidate axis is padded to the 128-lane boundary and kept resident per
 block, so the whole step is one VPU compare + masked lane-min per tile — no
 gathers (the per-candidate fill positions are pre-gathered by the caller,
 which is a cheap ``fill[safe_req]`` index outside the kernel).  Grid tiles
-the row axis only; blocks are ``(block_n, Kp)`` int32 in VMEM.
+the row axis only; blocks are ``(block_n, Kp)`` int32 in VMEM, and the
+result is written as a ``(block_n, 1)`` column.
 
 ``interpret`` defaults to True off-TPU (same convention as
-:mod:`repro.kernels.ops`), giving a bit-identical CPU fallback; the oracle
-lives in :mod:`repro.accel.kernels.ref`.
+:mod:`repro.kernels.ops`), which is how the CPU tests run it against the
+oracle in :mod:`repro.accel.kernels.ref`.
 """
 from __future__ import annotations
 
@@ -29,7 +30,8 @@ from jax.experimental import pallas as pl
 def _kernel(elig_ref, fill_ref, pos_ref, o_ref, *, kp: int):
     avail = (elig_ref[...] != 0) & (fill_ref[...] >= pos_ref[...])
     iota = jax.lax.broadcasted_iota(jnp.int32, avail.shape, 1)
-    o_ref[...] = jnp.min(jnp.where(avail, iota, jnp.int32(kp)), axis=1)
+    o_ref[...] = jnp.min(jnp.where(avail, iota, jnp.int32(kp)), axis=1,
+                         keepdims=True)
 
 
 def _default_interpret() -> bool:
@@ -71,8 +73,11 @@ def masked_first_fit(elig: jax.Array, fillcand: jax.Array, pos: jax.Array,
             pl.BlockSpec((bn, kp), lambda ni: (ni, 0)),
             pl.BlockSpec((bn, 1), lambda ni: (ni, 0)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda ni: (ni,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.int32),
+        # a (bn, 1) column block: a 1-D (bn,) output block is refused by
+        # Mosaic once the grid has more than one block (its tiling differs
+        # from the XLA layout of the whole array)
+        out_specs=pl.BlockSpec((bn, 1), lambda ni: (ni, 0)),
+        out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.int32),
         interpret=interpret,
     )(elig_i, fill_i, pos_i)
-    return jnp.minimum(out[:n], jnp.int32(K))
+    return jnp.minimum(out[:n, 0], jnp.int32(K))

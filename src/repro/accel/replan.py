@@ -69,6 +69,7 @@ import numpy as np
 from ..core.dispatch import DispatchTable, _NO_BAND
 from ..core.irs import SchedulePlan, atom_priorities, inter_group_allocate
 from ..core.types import Job, JobGroup, JobRequest
+from ..obs import metrics as _obsmetrics
 from ..obs import trace as _obstrace
 
 
@@ -88,6 +89,10 @@ def _kernel_order(ids: np.ndarray, keys: np.ndarray) -> np.ndarray:
     (accelerator-resident runs, ``REPRO_REPLAN_ORDER=kernel``), holding the
     NumPy path's bit-exactness bar.
 
+    The arrays are padded to a power-of-two bucket of at least 128 rows, so
+    the kernel compiles once per bucket rather than once per group size;
+    padded rows form a segment of their own and rank nothing in the real one.
+
     The kernel ranks on f32 keys, so its permutation can deviate from the
     f64 ``np.lexsort`` when keys collide only after f32 rounding.  The guard
     is a strict-order check on the *f64* keys under the returned
@@ -95,23 +100,36 @@ def _kernel_order(ids: np.ndarray, keys: np.ndarray) -> np.ndarray:
     strict total order, so a permutation passing the check IS the unique
     sorted order (a non-permutation repeats an element and fails the strict
     comparison).  Any failure falls back to ``np.lexsort`` — exactness never
-    depends on the kernel."""
+    depends on the kernel.  Registry counters ``accel.kernel_order_calls``
+    and ``accel.kernel_order_fallbacks`` count both outcomes."""
     n = len(ids)
     if n < 2:
         return np.arange(n, dtype=np.int64)
+    reg = _obsmetrics.REGISTRY
     if ids.min() < _I32_MIN or ids.max() > _I32_MAX:
+        if reg.enabled:
+            reg.counter("accel.kernel_order_fallbacks").inc()
         return np.lexsort((ids, keys))
     import jax.numpy as jnp
 
     from .kernels.replan_order import segmented_order
-    perm = np.asarray(segmented_order(
-        jnp.asarray(np.zeros(n, dtype=np.int32)),     # one segment
-        jnp.asarray(keys.astype(np.float32)),
-        jnp.asarray(ids.astype(np.int32)))).astype(np.int64)
+    bucket = max(128, 1 << (n - 1).bit_length())
+    seg = np.ones(bucket, dtype=np.int32)
+    seg[:n] = 0
+    key32 = np.zeros(bucket, dtype=np.float32)
+    key32[:n] = keys
+    tie = np.arange(bucket, dtype=np.int32)
+    tie[:n] = ids
+    perm = np.asarray(segmented_order(jnp.asarray(seg), jnp.asarray(key32),
+                                      jnp.asarray(tie)))[:n].astype(np.int64)
+    if reg.enabled:
+        reg.counter("accel.kernel_order_calls").inc()
     k = keys[perm]
     i = ids[perm]
     if bool(np.all((k[:-1] < k[1:]) | ((k[:-1] == k[1:]) & (i[:-1] < i[1:])))):
         return perm
+    if reg.enabled:
+        reg.counter("accel.kernel_order_fallbacks").inc()
     return np.lexsort((ids, keys))
 
 

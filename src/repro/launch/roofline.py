@@ -119,15 +119,9 @@ class GraphCost:
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across jaxlib versions.
-
-    Older jaxlib returns a one-element list of per-program dicts; newer
-    returns the dict directly (and ``None`` when analysis is unavailable).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``compiled.cost_analysis()`` as a dict (empty when the backend gives
+    no analysis)."""
+    return compiled.cost_analysis() or {}
 
 
 def graph_cost(compiled) -> GraphCost:

@@ -15,6 +15,7 @@ import sys
 from pathlib import Path
 from typing import List, Optional
 
+from ..accel.engine import use_compile_cache
 from . import library  # noqa: F401  (populates the registry)
 from .runner import DEFAULT_SCHEDS, comparison_table, run_scenario
 from .spec import all_scenarios, get_scenario, scenario_names
@@ -83,6 +84,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     if args.cmd == "list":
         for spec in all_scenarios():
             print(f"{spec.name:<22} {spec.description}")

@@ -14,11 +14,7 @@ import math
 import numpy as np
 import pytest
 
-try:        # property tests run under hypothesis when present, and fall
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:                               # pragma: no cover
-    HAVE_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro.accel.engine import (ArrayMatchEngine, match_chunk,
                                 match_chunk_jax, match_chunk_seq)
@@ -94,11 +90,10 @@ def test_match_chunk_equals_sequential_oracle(seed):
     _check_matcher_equals_oracle(seed, n=1 + 7 * seed % 80)
 
 
-if HAVE_HYPOTHESIS:
-    @settings(max_examples=60, deadline=None)
-    @given(st.integers(0, 10_000), st.integers(1, 80))
-    def test_match_chunk_equals_sequential_oracle_hyp(seed, n):
-        _check_matcher_equals_oracle(seed, n)
+@settings(max_examples=60, deadline=None)
+@given(st.integers(0, 10_000), st.integers(1, 80))
+def test_match_chunk_equals_sequential_oracle_hyp(seed, n):
+    _check_matcher_equals_oracle(seed, n)
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
@@ -354,13 +349,12 @@ def test_array_engine_equivalent_on_random_workloads(seed, sched_name, rate):
     _check_engine_equivalence(seed, sched_name, rate)
 
 
-if HAVE_HYPOTHESIS:
-    @settings(max_examples=10, deadline=None)
-    @given(st.integers(0, 1_000),
-           st.sampled_from(["venn", "random", "srsf"]), st.floats(0.5, 4.0))
-    def test_array_engine_equivalent_on_random_workloads_hyp(
-            seed, sched_name, rate):
-        _check_engine_equivalence(seed, sched_name, rate)
+@settings(max_examples=10, deadline=None)
+@given(st.integers(0, 1_000),
+       st.sampled_from(["venn", "random", "srsf"]), st.floats(0.5, 4.0))
+def test_array_engine_equivalent_on_random_workloads_hyp(
+        seed, sched_name, rate):
+    _check_engine_equivalence(seed, sched_name, rate)
 
 
 def test_array_engine_equivalent_with_tiering_and_contention():
@@ -375,6 +369,42 @@ def test_array_engine_equivalent_with_tiering_and_contention():
     assert m1.jcts == m2.jcts
     assert m1.rounds == m2.rounds
     assert s2.engine.segments > 0             # the array path actually ran
+
+
+@pytest.mark.parametrize("platform,want", [("cpu", ("numpy", False)),
+                                           ("tpu", ("jax", True))])
+def test_backend_follows_platform(platform, want, monkeypatch):
+    """The platform alone picks the matcher: NumPy on the CPU (the test
+    path), the jitted fixed point with the Pallas kernel on a TPU."""
+    import jax
+    assert jax.default_backend() == "cpu"
+    if platform == "tpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    engine = ArrayMatchEngine()
+    assert (engine.backend, engine.use_kernel) == want
+    sim = Simulator(generate_jobs(JobTraceConfig(num_jobs=1)),
+                    VennScheduler(), engine="array")
+    assert (sim.engine.backend, sim.engine.use_kernel) == want
+
+
+def test_backend_exception_propagates(monkeypatch):
+    """A device failure surfaces out of match(): no segment is quietly
+    served by the host oracle."""
+    import repro.accel.engine as engine_mod
+
+    def lost(*args, **kwargs):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(engine_mod, "match_chunk_jax", lost)
+    sched = FakeSched([[(FakeReq(5), -math.inf, math.inf)]])
+    sched.prepare_match = lambda now: None
+    sched.match_token = lambda: ("t",)
+    sched.index = type("I", (), {"num_atoms": 1})()
+    engine = ArrayMatchEngine(backend="jax")
+    engine.prepare(sched, 0.0)
+    with pytest.raises(RuntimeError, match="device lost"):
+        engine.match(np.zeros(40, dtype=np.int64), np.ones(40))
+    assert engine.degraded_segments == 0
 
 
 def test_unknown_engine_rejected():

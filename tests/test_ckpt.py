@@ -99,8 +99,6 @@ def test_async_checkpointer(tmp_path):
                                   np.asarray(_tree(3)["w"]))
 
 
-@pytest.mark.skipif(not hasattr(jax.sharding, "AxisType"),
-                    reason="jax.sharding.AxisType requires a newer jax")
 def test_elastic_restore_onto_sharding(tmp_path):
     """Restore places leaves with a target sharding (mesh-shape agnostic)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

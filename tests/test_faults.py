@@ -451,7 +451,6 @@ def test_registry_sweep_under_random_plans_never_raises():
 
 
 def test_randomized_fault_plans_never_raise():
-    hypothesis = pytest.importorskip("hypothesis")
     from hypothesis import given, settings
     from hypothesis import strategies as st
 

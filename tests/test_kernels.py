@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis", reason="hypothesis not installed")
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref

@@ -3,7 +3,6 @@ import math
 
 import pytest
 
-pytest.importorskip("hypothesis", reason="hypothesis not installed")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (FifoScheduler, RandomScheduler, SrsfScheduler,

@@ -279,7 +279,6 @@ def test_kernel_order_matches_lexsort():
     """REPRO_REPLAN_ORDER=kernel resolves ties and magnitudes exactly like
     the NumPy lexsort path (the f64 strict-order guard falls back on any
     f32-rank ambiguity, so the permutation is always the unique one)."""
-    pytest.importorskip("jax")
     from repro.accel.replan import _kernel_order
 
     rng = np.random.default_rng(0)
@@ -293,10 +292,31 @@ def test_kernel_order_matches_lexsort():
         assert np.array_equal(got, want), f"n={n}"
 
 
+def test_kernel_order_pads_group_sizes_to_shared_buckets():
+    """Group sizes that share a power-of-two bucket (>= 128) share one
+    compiled kernel, and the padding rows never reach the permutation."""
+    from repro import obs
+    from repro.accel.kernels.replan_order import segmented_rank
+    from repro.accel.replan import _kernel_order
+
+    rng = np.random.default_rng(1)
+    sizes = (2, 5, 100, 128, 129, 200, 256)
+    before = segmented_rank._cache_size()
+    with obs.session(tracing=False, metrics=True) as (_, reg):
+        for n in sizes:
+            keys = rng.choice([0.5, 1.0, 3.0, 7.25], size=n)
+            ids = rng.permutation(10 * n)[:n].astype(np.int64)
+            assert np.array_equal(_kernel_order(ids, keys),
+                                  np.lexsort((ids, keys))), n
+        calls = reg.counter("accel.kernel_order_calls").value
+        fallbacks = reg.counter("accel.kernel_order_fallbacks").value
+    assert (calls, fallbacks) == (len(sizes), 0)
+    assert segmented_rank._cache_size() - before <= 2   # buckets 128, 256
+
+
 def test_kernel_order_backend_plan_equivalent(monkeypatch):
     """Full step-level equivalence with the Pallas segmented_order resort
     path enabled (paranoid self-check active via the autouse fixture)."""
-    pytest.importorskip("jax")
     monkeypatch.setenv("REPRO_REPLAN_ORDER", "kernel")
     _drive_script(2, steps=25)
 

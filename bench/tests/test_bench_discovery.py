@@ -1,0 +1,68 @@
+"""Cells, configurations, traffic mixes and per-layer metrics are found by
+name: in a copy of the benchmark, new files plus new entries in
+``BENCHMARK.json`` give a new cell and a new metric, and no file that was
+there changes."""
+import hashlib
+import json
+import shutil
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from bench import run        # noqa: E402
+
+READER = '''"""Device calls per check-in consumed."""
+
+
+def read(ctx):
+    return ctx["checkins"] and ctx["backend_calls"] / ctx["checkins"]
+'''
+
+
+def _digests(root: Path):
+    return {p.relative_to(root): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in (root / "bench").rglob("*") if p.is_file()}
+
+
+def test_added_files_are_found_by_name(tmp_path):
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    before = _digests(tmp_path)
+
+    cfg = json.loads((tmp_path / "bench/configs/even4.json").read_text())
+    cfg["fleet"]["cpu_med"] = 3.0
+    (tmp_path / "bench/configs/even4_poorer.json").write_text(json.dumps(cfg))
+    (tmp_path / "bench/traffic/r40.json").write_text(json.dumps(
+        {"base_rate": 40, "num_jobs": 8, "mean_interarrival_s": 60.0,
+         "episode_sim_s": 300.0, "batch_sim_s": 10.0}))
+    (tmp_path / "bench/metrics/calls_per_checkin.py").write_text(READER)
+    bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "even4_poorer", "source": "test",
+                             "file": "bench/configs/even4_poorer.json",
+                             "reduced": ["fleet"], "why": "test"})
+    bench["workloads"].append({"name": "even4_poorer.r40",
+                               "config": "even4_poorer", "traffic": "r40",
+                               "chips": 1, "why": "test"})
+    bench["per_layer"].append({"name": "calls_per_checkin", "unit": "1",
+                               "better": "lower", "source": "program_counter",
+                               "layer": "drain", "moves": "checkins_per_s",
+                               "workloads": ["even4_poorer.r40"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    cell = run.resolve(run.load_benchmark(tmp_path), "even4_poorer.r40")
+    assert (cell["config"], cell["traffic"]) == ("even4_poorer", "r40")
+    assert "calls_per_checkin" in {m["name"] for m in cell["per_layer"]}
+    assert "calls_per_checkin" not in {
+        m["name"] for m in run.resolve(run.load_benchmark(tmp_path),
+                                       "even4.r2")["per_layer"]}
+
+    line, _ = run.run_cell("even4_poorer.r40", 3, 0.3, True, root=tmp_path,
+                           require_tpu=False, log=lambda *a, **k: None)
+    assert line["correct"] is True
+    assert "calls_per_checkin" in line["metrics"]
+    after = _digests(tmp_path)
+    assert all(after[p] == d for p, d in before.items())

@@ -21,7 +21,8 @@ from .kernels.schedule_match import masked_first_fit
 def _match_jax(reqix, elig, rem, use_kernel=False):
     """``reqix``/``elig``: (n, K); ``rem``: (R,).  Padded rows have no
     eligible slot, padded requests have ``rem == 0``.  Returns
-    ``(choice, granted)`` over the padded row axis."""
+    ``(choice, granted)`` over the padded row axis and the fixed point's
+    iteration count."""
     n, K = reqix.shape
     R = rem.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
@@ -67,11 +68,11 @@ def _match_jax(reqix, elig, rem, use_kernel=False):
         _, cur, it = carry
         return cur, fills_of(choice_of(cur)), it + 1
 
-    _, fill, _ = jax.lax.while_loop(
+    _, fill, iters = jax.lax.while_loop(
         cond, body, (fill0 - 1, fill0, jnp.int32(0)))
     choice = choice_of(fill)
     ch_s, p_s, rank, valid = ranks_of(choice)
     remg = rem[jnp.minimum(ch_s, R - 1)]
     g_sorted = valid & (rank < remg)
     granted = jnp.zeros(n, dtype=bool).at[p_s].set(g_sorted)
-    return choice, granted
+    return choice, granted, iters

@@ -193,20 +193,18 @@ def _pow2(x: int) -> int:
     return 1 << max(0, (x - 1)).bit_length()
 
 
-# padded shapes already dispatched (per process): a jax call at an unseen
-# shape compiles; at a seen shape it only executes.  Used to label trace
-# spans — populated only while tracing, so an enable() mid-run labels the
-# first call per shape as compiling even if jit already cached it.
-_seen_jax_shapes: set = set()
-
-
 def match_chunk_jax(atom_ids: np.ndarray, speeds: np.ndarray,
                     state: MatchState, use_kernel: bool = False
                     ) -> MatchResult:
     """Jitted fixed point.  Shapes are padded to powers of two so replaying
     many segment sizes reuses a handful of compiled programs; with
     ``use_kernel=True`` the inner masked first-fit runs as the Pallas kernel
-    (interpret mode off-TPU)."""
+    (interpret mode off-TPU).
+
+    Traced as four children of ``accel.match``: ``accel.jax.pack`` (host
+    gather and padding), ``.put`` (the host-to-device copies, enqueued),
+    ``.run`` (the jitted call, dispatched) and ``.fetch`` (the host waits
+    for the device and copies the outputs back)."""
     import jax.numpy as jnp
 
     from ._jax_impl import _match_jax
@@ -216,6 +214,9 @@ def match_chunk_jax(atom_ids: np.ndarray, speeds: np.ndarray,
     if n == 0 or R == 0:
         return MatchResult(np.full(n, -1, dtype=np.int64),
                            np.zeros(n, dtype=bool))
+    tr = _obstrace.TRACER
+    reg = _obsmetrics.REGISTRY
+    tok = tr.begin("accel.jax.pack", cat="accel") if tr.enabled else None
     reqix = state.cand_req[atom_ids]
     sp = speeds[:, None]
     elig = (reqix >= 0) & (state.cand_lo[atom_ids] <= sp) \
@@ -228,19 +229,27 @@ def match_chunk_jax(atom_ids: np.ndarray, speeds: np.ndarray,
     elig_p[:n, :elig.shape[1]] = elig
     rem_p = np.zeros(rp, dtype=np.int32)
     rem_p[:R] = rem
-    tr = _obstrace.TRACER
-    if tr.enabled:
-        shape = (np_pad, rp, kp, use_kernel)
-        name = "accel.jax.exec" if shape in _seen_jax_shapes \
-            else "accel.jax.compile+exec"
-        _seen_jax_shapes.add(shape)
-        tok = tr.begin(name, cat="accel", n=np_pad, r=rp, k=kp)
-    else:
-        tok = None
-    choice, granted = _match_jax(jnp.asarray(reqix_p), jnp.asarray(elig_p),
-                                 jnp.asarray(rem_p), use_kernel=use_kernel)
+    h2d = reqix_p.nbytes + elig_p.nbytes + rem_p.nbytes
+    if tok is not None:
+        tr.end(tok, n=np_pad, r=rp, k=kp)
+        tok = tr.begin("accel.jax.put", cat="accel", bytes=h2d)
+    args = jnp.asarray(reqix_p), jnp.asarray(elig_p), jnp.asarray(rem_p)
+    if tok is not None:
+        tr.end(tok)
+        tok = tr.begin("accel.jax.run", cat="accel")
+    choice, granted, iters = _match_jax(*args, use_kernel=use_kernel)
+    if reg.enabled:
+        iters.copy_to_host_async()      # rides along with the outputs
+    if tok is not None:
+        tr.end(tok)
+        tok = tr.begin("accel.jax.fetch", cat="accel")
     out = MatchResult(np.asarray(choice)[:n].astype(np.int64),
                       np.asarray(granted)[:n])
+    if reg.enabled:
+        reg.counter("accel.jax_calls").inc()
+        reg.counter("accel.h2d_bytes").inc(h2d)
+        reg.histogram("accel.fixedpoint_iters", lo=1.0, hi=1e3,
+                      buckets_per_decade=20).record(int(iters))
     if tok is not None:
         tr.end(tok)
     return out

@@ -35,9 +35,16 @@ Use :func:`enable`/:func:`disable` or the :func:`session` context manager::
 
 ``python -m repro.obs summarize t.json [m.jsonl]`` prints top-spans by
 self-time, histogram percentile tables, and per-job timelines.
+
+While the tracer or the registry is enabled, a ``gc.callbacks`` hook times
+every garbage collection: a ``py.gc`` span (cat ``py``) and the
+``py.gc_wall_s`` / ``py.gc_collections.gen<N>`` counters.  With both
+disabled no hook is installed.
 """
 from __future__ import annotations
 
+import gc
+import time
 from contextlib import contextmanager
 from typing import Optional
 
@@ -61,11 +68,52 @@ __all__ = [
 ]
 
 
+class _GcHook:
+    """``gc.callbacks`` entry: one ``py.gc`` span and the collection
+    counters per collection, read from the globals at each ``start``."""
+
+    def __init__(self):
+        self._open = None       # (tracer, token, t0) of the collection
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            tr = _trace_mod.TRACER
+            tok = tr.begin("py.gc", cat="py",
+                           generation=info["generation"]) \
+                if tr.enabled else None
+            self._open = (tr, tok, time.perf_counter())
+            return
+        if self._open is None:
+            return
+        tr, tok, t0 = self._open
+        self._open = None
+        reg = _metrics_mod.REGISTRY
+        if reg.enabled:
+            reg.counter("py.gc_wall_s").inc(time.perf_counter() - t0)
+            reg.counter(_GC_COUNTERS[info["generation"]]).inc()
+        if tok is not None:
+            tr.end(tok, collected=info["collected"])
+
+
+_GC_COUNTERS = tuple(f"py.gc_collections.gen{g}" for g in range(3))
+_GC_HOOK = _GcHook()
+
+
+def _sync_gc_hook() -> None:
+    """Install the collection hook iff the tracer or the registry is on."""
+    on = _trace_mod.TRACER.enabled or _metrics_mod.REGISTRY.enabled
+    if on and _GC_HOOK not in gc.callbacks:
+        gc.callbacks.append(_GC_HOOK)
+    elif not on and _GC_HOOK in gc.callbacks:
+        gc.callbacks.remove(_GC_HOOK)
+
+
 def enable(tracing: bool = True, metrics: bool = True,
            max_events: int = 1_000_000,
            categories=None,
            audit: bool = False,
-           grant_sample: int = DEFAULT_GRANT_SAMPLE):
+           grant_sample: int = DEFAULT_GRANT_SAMPLE,
+           profiler: bool = False):
     """Install a live tracer and/or registry as the process globals.
 
     Returns ``(tracer, registry)`` — the null singletons for whichever side
@@ -78,14 +126,19 @@ def enable(tracing: bool = True, metrics: bool = True,
     :func:`get_audit`, export with ``write_jsonl``).  ``grant_sample``
     audits every Nth round-opening grant — 1 (the default) records one
     grant per round.
+
+    ``profiler=True`` mirrors the tracer's spans into the JAX profiler
+    (:class:`~repro.obs.trace.Tracer`), putting them on the profiler's
+    clock while a profiler session records.
     """
     if tracing:
         _trace_mod.TRACER = Tracer(max_events=max_events,
-                                   categories=categories)
+                                   categories=categories, profiler=profiler)
     if metrics:
         _metrics_mod.REGISTRY = MetricsRegistry()
     if audit:
         _audit_mod.AUDIT = AuditRecorder(grant_sample=grant_sample)
+    _sync_gc_hook()
     return _trace_mod.TRACER, _metrics_mod.REGISTRY
 
 
@@ -95,6 +148,7 @@ def disable() -> None:
     _trace_mod.TRACER = NULL_TRACER
     _metrics_mod.REGISTRY = NULL_REGISTRY
     _audit_mod.AUDIT = NULL_AUDIT
+    _sync_gc_hook()
 
 
 def get_tracer():
@@ -136,3 +190,4 @@ def session(tracing: bool = True, metrics: bool = True,
         _trace_mod.TRACER = prev_tr
         _metrics_mod.REGISTRY = prev_reg
         _audit_mod.AUDIT = prev_aud
+        _sync_gc_hook()

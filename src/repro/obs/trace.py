@@ -9,10 +9,12 @@ keeps the hot path free:
   guarded sites cost one module-attribute lookup plus a bool check, and the
   unguarded convenience API (``span``/``begin``/``end``/``instant``) is a
   no-op method on a ``__slots__ = ()`` singleton.  No event storage exists.
-* **enabled** — spans/instants are appended to an in-memory list of Chrome
-  trace events (``ph="X"`` complete spans with microsecond ``ts``/``dur`` on
-  the tracer's monotonic clock, ``ph="i"`` instants), tagged with the
-  emitting thread id.  Instrumentation only ever *reads* simulation state, so
+* **enabled** — spans/instants are appended to an in-memory list as flat
+  tuples and read back as Chrome trace events (``ph="X"`` complete spans
+  with microsecond ``ts``/``dur`` on the tracer's monotonic clock,
+  ``ph="i"`` instants), tagged with the emitting thread id.  A tuple of
+  plain values drops out of the garbage collector's tracking, so a long
+  trace adds nothing to every collection, where a dict per event would.  Instrumentation only ever *reads* simulation state, so
   enabling tracing never changes scheduling outcomes — ``SimMetrics`` stays
   bit-identical (enforced by ``tests/test_obs.py``).
 
@@ -24,6 +26,13 @@ is dropped and counted, never raised.  ``categories`` restricts recording to
 a set of span categories (e.g. ``{"sched"}`` to record only replan spans on
 an otherwise expensive run).
 
+``Tracer(profiler=True)`` also mirrors every span into the JAX profiler:
+``begin`` enters a ``jax.profiler.TraceAnnotation`` of the span's name and
+``end`` exits it, so while a profiler session records, the program's spans
+sit on the profiler's host plane, on the same clock as the device's
+operations.  JAX is imported only then: without it this module stays pure
+stdlib.
+
 Export: ``write(path)`` dumps ``{"traceEvents": [...]}`` — the JSON object
 format of the Chrome trace-event spec, loadable in Perfetto
 (https://ui.perfetto.dev) or ``chrome://tracing``.
@@ -34,6 +43,7 @@ import json
 import os
 import threading
 import time
+from itertools import chain
 from typing import Dict, List, Optional
 
 __all__ = ["NULL_SPAN", "NULL_TRACER", "NullTracer", "Tracer", "TRACER",
@@ -76,10 +86,6 @@ class NullTracer:
         pass
 
     def instant(self, name: str, cat: str = "repro", **args) -> None:
-        pass
-
-    def complete(self, name: str, start_us: float, dur_us: float,
-                 cat: str = "repro", **args) -> None:
         pass
 
     def now_us(self) -> float:
@@ -125,11 +131,18 @@ class Tracer:
 
     def __init__(self, max_events: int = 1_000_000,
                  categories=None,
-                 clock=time.perf_counter):
+                 clock=time.perf_counter,
+                 profiler: bool = False):
         self._clock = clock
+        self._annotation = None
+        if profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self._t0 = clock()
         self.pid = os.getpid()
-        self.events: List[dict] = []
+        # (name, cat, ts_us, dur_us or None for an instant, tid,
+        #  arg key, arg value, ...): one flat tuple per event
+        self._events: List[tuple] = []
         self.max_events = max_events
         self.dropped = 0
         self.categories = frozenset(categories) if categories else None
@@ -154,58 +167,63 @@ class Tracer:
         category is filtered out — ``end(None)`` is a no-op)."""
         if self.categories is not None and cat not in self.categories:
             return None
-        return [name, cat, args, self._clock(), threading.get_ident()]
+        ann = None
+        if self._annotation is not None:
+            ann = self._annotation(name)
+            ann.__enter__()
+        return [name, cat, args, self._clock(), threading.get_ident(), ann]
 
     def end(self, token, **args) -> None:
         if token is None:
             return
-        name, cat, targs, t0, tid = token
+        name, cat, targs, t0, tid, ann = token
+        if ann is not None:
+            ann.__exit__(None, None, None)
         if args:
             targs.update(args)
-        ev = {"name": name, "ph": "X", "ts": self.us(t0),
-              "dur": (self._clock() - t0) * 1e6,
-              "pid": self.pid, "tid": tid, "cat": cat}
-        if targs:
-            ev["args"] = targs
-        self._emit(ev)
-
-    def complete(self, name: str, start_us: float, dur_us: float,
-                 cat: str = "repro", **args) -> None:
-        """Emit a complete span from externally measured times (µs on this
-        tracer's clock — see :meth:`us`)."""
-        if self.categories is not None and cat not in self.categories:
-            return
-        ev = {"name": name, "ph": "X", "ts": start_us, "dur": dur_us,
-              "pid": self.pid, "tid": threading.get_ident(), "cat": cat}
-        if args:
-            ev["args"] = args
-        self._emit(ev)
+        self._emit((name, cat, self.us(t0), (self._clock() - t0) * 1e6, tid,
+                    *chain.from_iterable(targs.items())))
 
     def instant(self, name: str, cat: str = "repro", **args) -> None:
         if self.categories is not None and cat not in self.categories:
             return
-        ev = {"name": name, "ph": "i", "s": "t", "ts": self.now_us(),
-              "pid": self.pid, "tid": threading.get_ident(), "cat": cat}
-        if args:
-            ev["args"] = args
-        self._emit(ev)
+        self._emit((name, cat, self.now_us(), None, threading.get_ident(),
+                    *chain.from_iterable(args.items())))
 
-    def _emit(self, ev: dict) -> None:
-        if len(self.events) >= self.max_events:
+    def _emit(self, ev: tuple) -> None:
+        if len(self._events) >= self.max_events:
             self.dropped += 1
             return
-        self.events.append(ev)
+        self._events.append(ev)
 
     # ------------------------------------------------------------- export
 
     @property
     def num_events(self) -> int:
-        return len(self.events)
+        return len(self._events)
+
+    @property
+    def events(self) -> List[dict]:
+        """The recorded events as Chrome trace-event dicts, built anew on
+        each access."""
+        pid = self.pid
+        out = []
+        for name, cat, ts, dur, tid, *args in self._events:
+            if dur is None:
+                ev = {"name": name, "ph": "i", "s": "t", "ts": ts,
+                      "pid": pid, "tid": tid, "cat": cat}
+            else:
+                ev = {"name": name, "ph": "X", "ts": ts, "dur": dur,
+                      "pid": pid, "tid": tid, "cat": cat}
+            if args:
+                ev["args"] = dict(zip(args[::2], args[1::2]))
+            out.append(ev)
+        return out
 
     def export(self) -> Dict:
         """The Chrome trace-event JSON object format."""
         return {
-            "traceEvents": list(self.events),
+            "traceEvents": self.events,
             "displayTimeUnit": "ms",
             "otherData": {"tool": "repro.obs", "pid": self.pid,
                           "dropped_events": self.dropped},

@@ -194,6 +194,8 @@ class Simulator:
         engine_name = "array" if self.engine is not None else "python"
         while self._done < n_jobs:
             # ---- drain device check-ins until the heap takes priority ----
+            tok = tr.begin("sim.drain", cat="sim", engine=engine_name) \
+                if tr.enabled else None
             t0 = perf()
             seen0 = self.checkins_seen
             stopped = drain(bound)
@@ -210,9 +212,8 @@ class Simulator:
                         reg.histogram("sim.decision_latency_s",
                                       lo=1e-9, hi=1.0).record(dt / rows,
                                                               n=rows)
-                if rows and tr.enabled:
-                    tr.complete("sim.drain", tr.us(t0), dt * 1e6, cat="sim",
-                                rows=rows, engine=engine_name, sim_now=self.now)
+                if tok is not None:
+                    tr.end(tok, rows=rows, sim_now=self.now)
             if stopped:
                 # a check-in crossed the bound; only a horizon crossing ends
                 # the simulation — a pause bound leaves it resumable
@@ -368,6 +369,11 @@ class Simulator:
         index = sched.index
         grant = self._grant
         inf = math.inf
+        # per-segment spans and counters (grant application, scalar tail)
+        tr = _obstrace.TRACER
+        reg = _obsmetrics.REGISTRY
+        obs_on = tr.enabled or reg.enabled
+        perf = time.perf_counter
         while True:
             if self._chunk is None:
                 return False
@@ -424,7 +430,19 @@ class Simulator:
             if miss > 0:
                 hi = cursor + miss
             if hi - cursor < SCALAR_SEG_ROWS:
-                self._drain_array_scalar(state, cursor, hi, heap_t)
+                if not obs_on:
+                    self._drain_array_scalar(state, cursor, hi, heap_t)
+                    continue
+                tok = tr.begin("sim.drain_scalar", cat="sim") \
+                    if tr.enabled else None
+                t0 = perf()
+                grants = self._drain_array_scalar(state, cursor, hi, heap_t)
+                rows = self._cursor - cursor
+                if reg.enabled:
+                    reg.counter("sim.scalar_wall_s").inc(perf() - t0)
+                    reg.counter("sim.scalar_rows").inc(rows)
+                if tok is not None:
+                    tr.end(tok, rows=rows, grants=grants)
                 continue
             try:
                 res = engine.match(aids_np[cursor:hi], ck.speed[cursor:hi])
@@ -433,6 +451,10 @@ class Simulator:
             choice = res.choice
             seg_end = hi
             top = heap_t
+            if obs_on:
+                tok = tr.begin("sim.grants", cat="sim") \
+                    if tr.enabled else None
+                t0 = perf()
             for p in np.flatnonzero(res.granted).tolist():
                 i = cursor + p
                 if i >= seg_end:
@@ -455,6 +477,14 @@ class Simulator:
                                               side="right"))
                     if cut < seg_end:
                         seg_end = cut
+            if obs_on:
+                if reg.enabled:
+                    reg.counter("sim.grant_wall_s").inc(perf() - t0)
+                if tok is not None:
+                    # every granted row below seg_end was applied: a cut
+                    # never falls at or before the grant that armed it
+                    tr.end(tok, rows=seg_end - cursor, grants=int(
+                        np.count_nonzero(res.granted[:seg_end - cursor])))
             self._cursor = seg_end
             self.checkins_seen += seg_end - cursor
             self.now = times[seg_end - 1]
@@ -468,7 +498,8 @@ class Simulator:
         bounded out by the MISS scan).  Grants are mirrored into the state so
         later vectorized segments stay exact; if a grant surfaces a request
         the state does not know (a mid-row replan), the state is invalidated
-        and the caller's next ``prepare`` rebuilds it."""
+        and the caller's next ``prepare`` rebuilds it.  Returns the number
+        of grants applied."""
         heap = self._heap
         sched = self.sched
         grant = self._grant
@@ -478,6 +509,7 @@ class Simulator:
         n_cov = len(has_cand)
         top = heap_t
         i = cursor
+        grants = 0
         while i < hi:
             t_i = times[i]
             if top < t_i:
@@ -493,6 +525,7 @@ class Simulator:
                     or req.complete_time is not None):
                 continue
             filled = grant(req, i - 1, t_i, speed)
+            grants += 1
             rix = state.request_index(req)
             if rix is None:                     # request unknown to the
                 self.engine.invalidate()        # state (mid-row replan)
@@ -504,6 +537,7 @@ class Simulator:
         self._cursor = i
         self.checkins_seen += i - cursor
         self.now = times[i - 1]
+        return grants
 
     # ------------------------------------------------------------ internals
 
@@ -575,20 +609,17 @@ class Simulator:
 
     def _load_next_chunk(self) -> None:
         """Pull chunks from the stream until one has check-ins (or it ends)."""
+        tr = _obstrace.TRACER
+        tok = tr.begin("sim.chunk_load", cat="sim") if tr.enabled else None
         t0 = time.perf_counter()
         s0 = self.stream_seconds
         try:
             self._load_next_chunk_inner()
         finally:
             self.stream_seconds += time.perf_counter() - t0
-            tr = _obstrace.TRACER
-            if tr.enabled:
-                # span over the engine-comparable stream time (the inner
-                # loop backs the scalar mirror conversion out of the total)
-                tr.complete("sim.chunk_load", tr.us(t0),
-                            (self.stream_seconds - s0) * 1e6, cat="sim",
-                            rows=self._chunk.n if self._chunk is not None
-                            else 0)
+            if tok is not None:
+                tr.end(tok, rows=self._chunk.n if self._chunk is not None
+                       else 0)
             reg = _obsmetrics.REGISTRY
             if reg.enabled:
                 reg.counter("sim.stream_wall_s").inc(

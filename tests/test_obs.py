@@ -2,21 +2,32 @@
 component math:
 
 * **observe, never perturb**: tracing+metrics-enabled runs are bit-identical
-  (``summary()``, jcts, rounds) to disabled runs on BOTH drain engines,
-  across registry scenarios including a faulted one;
+  (``summary()``, jcts, rounds, the grant log) to disabled runs on BOTH
+  drain engines and on the jax backend, across registry scenarios including
+  a faulted one;
+* the device-call, grant and scalar-tail spans nest under their parents,
+  and the collection hook exists exactly while observability is on;
 * **zero-overhead when disabled**: the null tracer/registry singletons are
   the module globals by default, record nothing, and allocate nothing;
 * trace JSON round-trips and validates against the Chrome trace-event shape;
 * histogram percentile math (log buckets, weighted records, merge);
 * timeline decomposition sums to JCT; summarize self-time attribution.
 """
+import gc
 import json
 import math
+import os
+import subprocess
+import sys
 from dataclasses import replace
+from pathlib import Path
 
 import pytest
 
 from repro import obs
+from repro.accel.engine import ArrayMatchEngine
+from repro.core import SCHEDULERS
+from repro.faults.injector import FaultInjector
 from repro.obs import metrics as obsmetrics
 from repro.obs import trace as obstrace
 from repro.obs.metrics import Histogram, MetricsRegistry
@@ -24,6 +35,11 @@ from repro.obs.summarize import hist_table, span_stats, top_spans_table
 from repro.obs.timeline import build_timelines, timelines_from_records
 from repro.obs.trace import Tracer, validate_trace
 from repro.scenarios import fast_scaled, get_scenario, run_one
+from repro.scenarios.streams import build_jobs, build_stream
+from repro.sim.simulator import Simulator
+
+
+SRC = str(Path(__file__).resolve().parent.parent / "src")
 
 
 def _tiny(spec):
@@ -43,23 +59,111 @@ def _obs_disabled():
     obs.disable()
 
 
+def _simulate(spec, seed, engine):
+    """``run_one``'s Venn simulation, returning the simulator (grant log
+    kept).  ``engine="jax"``: the array drain on the jitted fixed point with
+    the Pallas kernel in interpret mode, the chip's path."""
+    if engine == "jax":
+        engine = ArrayMatchEngine(backend="jax", use_kernel=True)
+    plan = spec.fault_plan.resolve(spec.sim.max_time) \
+        if spec.fault_plan is not None else None
+    stream = build_stream(spec, seed)
+    if plan is not None and not plan.is_empty:
+        stream = FaultInjector(stream, plan)
+    sim = Simulator(build_jobs(spec, seed), SCHEDULERS["venn"](seed=seed),
+                    cfg=spec.sim, stream=stream, engine=engine, faults=plan,
+                    record_grants=True)
+    sim.run()
+    return sim
+
+
 # --------------------------------------------------- observe, never perturb
 
 # one plain scenario + one faulted scenario (blackout_storm exercises the
 # injector instants and the simulator's fault.blackout path)
 @pytest.mark.parametrize("scenario", ["baseline_even", "blackout_storm"])
-@pytest.mark.parametrize("engine", ["python", "array"])
+@pytest.mark.parametrize("engine", ["python", "array", "jax"])
 def test_traced_run_bit_identical(scenario, engine):
     spec = _tiny(get_scenario(scenario))
-    plain = run_one(spec, "venn", seed=1, engine=engine).metrics
+    plain = _simulate(spec, 1, engine)
     with obs.session(tracing=True, metrics=True) as (tr, reg):
-        traced = run_one(spec, "venn", seed=1, engine=engine).metrics
+        traced = _simulate(spec, 1, engine)
         n_events = tr.num_events
+    assert traced.grant_log == plain.grant_log
+    traced, plain = traced.metrics, plain.metrics
     assert traced.summary() == plain.summary()
     assert traced.jcts == plain.jcts
     assert traced.rounds == plain.rounds
     assert traced.resilience() == plain.resilience()
     assert n_events > 0          # the instrumentation actually fired
+
+
+def _inside(child, parents):
+    return any(p["tid"] == child["tid"] and p["ts"] <= child["ts"]
+               and child["ts"] + child["dur"] <= p["ts"] + p["dur"]
+               for p in parents)
+
+
+def test_device_call_grant_and_scalar_spans_nest():
+    spec = _tiny(get_scenario("baseline_even"))
+    with obs.session() as (tr, reg):
+        _simulate(spec, 0, "jax")
+        events = [e for e in tr.events if e["ph"] == "X"]
+        counters = {n: reg.get(n) for n in reg.names()}
+    by = {}
+    for e in events:
+        by.setdefault(e["name"], []).append(e)
+    calls = len(by["accel.jax.fetch"])
+    assert calls > 0
+    for name in ("accel.jax.pack", "accel.jax.put", "accel.jax.run",
+                 "accel.jax.fetch"):
+        assert len(by[name]) == calls
+        assert all(_inside(e, by["accel.match"]) for e in by[name]), name
+    for name in ("sim.grants", "sim.drain_scalar"):
+        assert by[name] and all(_inside(e, by["sim.drain"])
+                                for e in by[name]), name
+    assert {"accel.jax.compile+exec", "accel.jax.exec"}.isdisjoint(by)
+    put_bytes = sum(e["args"]["bytes"] for e in by["accel.jax.put"])
+    assert counters["accel.jax_calls"].value == calls
+    assert counters["accel.h2d_bytes"].value == put_bytes > 0
+    # the device path feeds the fixed point's iteration histogram
+    assert counters["accel.fixedpoint_iters"].count == calls
+    assert counters["accel.fixedpoint_iters"].vmin >= 1
+    assert counters["sim.scalar_rows"].value == sum(
+        e["args"]["rows"] for e in by["sim.drain_scalar"])
+    grants = sum(e["args"]["grants"] for e in by["sim.grants"]) \
+        + sum(e["args"]["grants"] for e in by["sim.drain_scalar"])
+    assert 0 < grants
+    assert counters["sim.grant_wall_s"].value > 0
+    assert counters["sim.scalar_wall_s"].value > 0
+
+
+def _gc_hooks():
+    return [cb for cb in gc.callbacks
+            if type(cb).__module__.startswith("repro.obs")]
+
+
+def test_gc_hook_records_collections_only_while_enabled():
+    assert _gc_hooks() == []
+    obs.enable(tracing=True, metrics=True)
+    assert len(_gc_hooks()) == 1
+    gc.collect()
+    tr, reg = obs.get_tracer(), obs.get_registry()
+    spans = [e for e in tr.events if e["name"] == "py.gc"]
+    assert spans and spans[-1]["cat"] == "py"
+    assert spans[-1]["args"]["generation"] == 2
+    assert spans[-1]["args"]["collected"] >= 0
+    assert reg.counter("py.gc_collections.gen2").value >= 1
+    assert reg.counter("py.gc_wall_s").value > 0
+    obs.disable()
+    assert _gc_hooks() == []
+    # metrics alone keep the hook (counters, no spans); leaving a session
+    # restores the hook-free state
+    with obs.session(tracing=False, metrics=True) as (_, reg):
+        assert len(_gc_hooks()) == 1
+        gc.collect()
+        assert reg.counter("py.gc_collections.gen2").value >= 1
+    assert _gc_hooks() == []
 
 
 def test_trace_has_expected_span_taxonomy(tmp_path):
@@ -113,6 +217,26 @@ def test_disabled_run_emits_zero_events():
     assert obstrace.TRACER is obstrace.NULL_TRACER      # still the singleton
 
 
+def test_obs_imports_and_runs_without_jax():
+    # repro.obs (and faults/recovery through it) stays pure stdlib: JAX is
+    # imported only for a tracer that mirrors into the profiler
+    code = ("import sys, gc; sys.modules['jax'] = None\n"
+            "from repro import obs\n"
+            "from repro.faults import recovery\n"
+            "tr, reg = obs.enable()\n"
+            "gc.collect()\n"
+            "assert reg.counter('py.gc_collections.gen2').value == 1\n"
+            "try:\n"
+            "    obs.enable(profiler=True)\n"
+            "except ImportError:\n"
+            "    print('no-jax-ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         env=dict(os.environ, PYTHONPATH=SRC))
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "no-jax-ok"
+
+
 def test_session_restores_singletons_on_error():
     with pytest.raises(RuntimeError):
         with obs.session():
@@ -142,6 +266,24 @@ def test_trace_round_trips_and_validates(tmp_path):
         assert e["ts"] >= 0 and isinstance(e["tid"], int)
     # writing is plain JSON — a second loader agrees
     assert json.loads((tmp_path / "t.json").read_text())["traceEvents"]
+
+
+def test_recorded_events_leave_the_collectors_tracking():
+    # a traced run keeps ~1M events; as plain tuples they drop out of the
+    # collector's tracking at the first collection, so they add no work to
+    # every later one (dicts with args stayed tracked)
+    tr = Tracer()
+    for i in range(100):
+        tok = tr.begin("s", cat="sim", rows=i)
+        tr.end(tok, grants=2, engine="array")
+        tr.instant("i", cat="fault", revoked=i)
+    gc.collect()
+    assert not any(gc.is_tracked(e) for e in tr._events)
+    ev = tr.events
+    assert ev[0]["args"] == {"rows": 0, "grants": 2, "engine": "array"}
+    assert ev[1] == {"name": "i", "ph": "i", "s": "t", "ts": ev[1]["ts"],
+                     "pid": tr.pid, "tid": ev[1]["tid"], "cat": "fault",
+                     "args": {"revoked": 0}}
 
 
 def test_validate_trace_rejects_malformed():

@@ -2,9 +2,11 @@
 
 Mirrors :func:`repro.accel.engine.match_chunk` on static padded shapes:
 ``lax.while_loop`` over the fill-position vector, with the inner masked
-first-fit either as the pure-jnp oracle or the Pallas kernel.  Inputs are
-int32 and power-of-two padded by the caller so many segment sizes share a
-handful of compiled programs.
+first-fit either as the pure-jnp oracle or the Pallas kernel.  The caller
+packs the inputs into one int32 buffer and reads one int32 buffer back
+(layouts in :func:`repro.accel.engine.match_chunk_jax`); shapes are
+power-of-two padded so many segment sizes share a handful of compiled
+programs.
 """
 from __future__ import annotations
 
@@ -17,16 +19,21 @@ from .kernels.ref import masked_first_fit_ref
 from .kernels.schedule_match import masked_first_fit
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel",))
-def _match_jax(reqix, elig, rem, use_kernel=False):
-    """``reqix``/``elig``: (n, K); ``rem``: (R,).  Padded rows have no
-    eligible slot, padded requests have ``rem == 0``.  Returns
-    ``(choice, granted)`` over the padded row axis and the fixed point's
-    iteration count."""
-    n, K = reqix.shape
+@functools.partial(jax.jit, static_argnames=("rows", "slots", "use_kernel"))
+def _match_jax(buf, rows, slots, use_kernel=False):
+    """``buf``: (rows * slots + R,) int32, the ``(rows, slots)`` candidate
+    matrix with eligibility folded in (``-1`` = not eligible) followed by
+    ``rem``.  Padded rows have no eligible slot, padded requests have
+    ``rem == 0``.  Returns one (2 * rows + 1,) int32 array: ``choice`` and
+    ``granted`` over the padded row axis, then the fixed point's iteration
+    count."""
+    n, K = rows, slots
+    reqix = buf[:n * K].reshape(n, K)
+    rem = buf[n * K:]
     R = rem.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
-    safe = jnp.where(reqix >= 0, reqix, 0).astype(jnp.int32)
+    elig = reqix >= 0
+    safe = jnp.where(elig, reqix, 0)
     elig_i = elig.astype(jnp.int32)
     first_fit = masked_first_fit if use_kernel else masked_first_fit_ref
 
@@ -74,5 +81,6 @@ def _match_jax(reqix, elig, rem, use_kernel=False):
     ch_s, p_s, rank, valid = ranks_of(choice)
     remg = rem[jnp.minimum(ch_s, R - 1)]
     g_sorted = valid & (rank < remg)
-    granted = jnp.zeros(n, dtype=bool).at[p_s].set(g_sorted)
-    return choice, granted, iters
+    granted = jnp.zeros(n, dtype=jnp.int32).at[p_s].set(
+        g_sorted.astype(jnp.int32))
+    return jnp.concatenate([choice, granted, iters[None]])

@@ -193,66 +193,85 @@ def _pow2(x: int) -> int:
     return 1 << max(0, (x - 1)).bit_length()
 
 
+def _pack_jax(atom_ids: np.ndarray, speeds: np.ndarray, state: MatchState
+              ) -> Tuple[np.ndarray, int, int]:
+    """The one input buffer of :func:`match_chunk_jax` (layout there) and
+    its padded ``(np_pad, kp)``."""
+    rem = state.remaining
+    reqix = state.cand_req[atom_ids]
+    sp = speeds[:, None]
+    elig = (reqix >= 0) & (state.cand_lo[atom_ids] <= sp) \
+        & (sp < state.cand_hi[atom_ids])
+    n, K = reqix.shape
+    np_pad, kp, rp = _pow2(n), _pow2(K), _pow2(len(rem))
+    cells = np_pad * kp
+    buf = np.full(cells + rp, -1, dtype=np.int32)
+    buf[:cells].reshape(np_pad, kp)[:n, :K] = np.where(elig, reqix, -1)
+    buf[cells:] = 0
+    buf[cells:cells + len(rem)] = rem
+    return buf, np_pad, kp
+
+
 def match_chunk_jax(atom_ids: np.ndarray, speeds: np.ndarray,
                     state: MatchState, use_kernel: bool = False
                     ) -> MatchResult:
-    """Jitted fixed point.  Shapes are padded to powers of two so replaying
-    many segment sizes reuses a handful of compiled programs; with
-    ``use_kernel=True`` the inner masked first-fit runs as the Pallas kernel
-    (interpret mode off-TPU).
+    """Jitted fixed point, fed by one host-to-device copy and read by one
+    device-to-host copy.  Shapes are padded to powers of two (``np_pad``
+    rows, ``kp`` slots, ``rp`` requests) so replaying many segment sizes
+    reuses a handful of compiled programs; with ``use_kernel=True`` the
+    inner masked first-fit runs as the Pallas kernel (interpret mode
+    off-TPU).
+
+    In: one flat int32 buffer of ``np_pad * kp + rp`` entries, the
+    row-major ``(np_pad, kp)`` candidate matrix with eligibility folded in
+    (``reqe = where(elig, reqix, -1)``; padding ``-1``) followed by
+    ``remaining`` (padding ``0``).  Folding is exact: the fixed point reads
+    a slot's request only where the slot is available, and available
+    implies eligible, so ``elig = reqe >= 0`` and ``reqix = reqe`` give the
+    same ``choice``, ``granted`` and iteration count.  The tier-band test
+    stays here on the host, in float64.
+
+    Out: one int32 array of ``2 * np_pad + 1`` entries, ``choice``, then
+    ``granted`` (0/1), then the fixed point's iteration count; its copy to
+    the host starts as soon as the call is dispatched.
 
     Traced as four children of ``accel.match``: ``accel.jax.pack`` (host
-    gather and padding), ``.put`` (the host-to-device copies, enqueued),
-    ``.run`` (the jitted call, dispatched) and ``.fetch`` (the host waits
-    for the device and copies the outputs back)."""
-    import jax.numpy as jnp
-
+    gather and packing), ``.put`` (empty, with the copy's ``bytes``),
+    ``.run`` (the host-to-device copy and the jitted call, dispatched) and
+    ``.fetch`` (the host waits for the device and reads the output)."""
     from ._jax_impl import _match_jax
     n = len(atom_ids)
-    rem = state.remaining
-    R = len(rem)
-    if n == 0 or R == 0:
+    if n == 0 or len(state.remaining) == 0:
         return MatchResult(np.full(n, -1, dtype=np.int64),
                            np.zeros(n, dtype=bool))
     tr = _obstrace.TRACER
     reg = _obsmetrics.REGISTRY
     tok = tr.begin("accel.jax.pack", cat="accel") if tr.enabled else None
-    reqix = state.cand_req[atom_ids]
-    sp = speeds[:, None]
-    elig = (reqix >= 0) & (state.cand_lo[atom_ids] <= sp) \
-        & (sp < state.cand_hi[atom_ids])
-    np_pad, rp = _pow2(n), _pow2(R)
-    kp = _pow2(reqix.shape[1])
-    reqix_p = np.full((np_pad, kp), -1, dtype=np.int32)
-    reqix_p[:n, :reqix.shape[1]] = reqix
-    elig_p = np.zeros((np_pad, kp), dtype=bool)
-    elig_p[:n, :elig.shape[1]] = elig
-    rem_p = np.zeros(rp, dtype=np.int32)
-    rem_p[:R] = rem
-    h2d = reqix_p.nbytes + elig_p.nbytes + rem_p.nbytes
+    buf, np_pad, kp = _pack_jax(atom_ids, speeds, state)
     if tok is not None:
-        tr.end(tok, n=np_pad, r=rp, k=kp)
-        tok = tr.begin("accel.jax.put", cat="accel", bytes=h2d)
-    args = jnp.asarray(reqix_p), jnp.asarray(elig_p), jnp.asarray(rem_p)
-    if tok is not None:
-        tr.end(tok)
+        tr.end(tok, n=np_pad, r=len(buf) - np_pad * kp, k=kp)
+        # the jitted call copies the NumPy buffer to the device as it
+        # dispatches, which the chip does faster than a device_put of its
+        # own: ``put`` only marks the copy's bytes, ``run`` holds its time
+        tr.end(tr.begin("accel.jax.put", cat="accel", bytes=buf.nbytes))
         tok = tr.begin("accel.jax.run", cat="accel")
-    choice, granted, iters = _match_jax(*args, use_kernel=use_kernel)
-    if reg.enabled:
-        iters.copy_to_host_async()      # rides along with the outputs
+    out = _match_jax(buf, np_pad, kp, use_kernel=use_kernel)
+    out.copy_to_host_async()
     if tok is not None:
         tr.end(tok)
         tok = tr.begin("accel.jax.fetch", cat="accel")
-    out = MatchResult(np.asarray(choice)[:n].astype(np.int64),
-                      np.asarray(granted)[:n])
+    host = np.asarray(out)
+    res = MatchResult(host[:n].astype(np.int64),
+                      host[np_pad:np_pad + n].astype(bool))
     if reg.enabled:
         reg.counter("accel.jax_calls").inc()
-        reg.counter("accel.h2d_bytes").inc(h2d)
+        reg.counter("accel.host_copies").inc(2)
+        reg.counter("accel.h2d_bytes").inc(buf.nbytes)
         reg.histogram("accel.fixedpoint_iters", lo=1.0, hi=1e3,
-                      buckets_per_decade=20).record(int(iters))
+                      buckets_per_decade=20).record(int(host[2 * np_pad]))
     if tok is not None:
         tr.end(tok)
-    return out
+    return res
 
 
 # JAX's persistent compilation cache, when JAX_COMPILATION_CACHE_DIR does not

@@ -9,6 +9,7 @@
   and SimMetrics to the per-device loop on randomized workloads, for Venn
   and the baselines.
 """
+import functools
 import math
 
 import numpy as np
@@ -96,18 +97,83 @@ def test_match_chunk_equals_sequential_oracle_hyp(seed, n):
     _check_matcher_equals_oracle(seed, n)
 
 
+def _edge_segment(case):
+    """A state and a segment at an edge of the packed device buffers."""
+    inf = math.inf
+    if case == "one_row":
+        reqs = [FakeReq(2), FakeReq(1)]
+        slots = [[(reqs[0], 1.0, 2.0), (reqs[1], -inf, inf)]]
+        aids, speeds = [0], [0.5]
+    elif case == "pow2_rows":                  # n == np_pad, no row padding
+        reqs = [FakeReq(d) for d in (5, 9, 30)]
+        slots = [[(reqs[0], -inf, inf), (reqs[1], 0.0, 1.5)],
+                 [(reqs[1], -inf, inf), (reqs[2], -inf, inf)]]
+        rng = np.random.default_rng(64)
+        aids, speeds = rng.integers(0, 2, 64), rng.uniform(0, 3, 64)
+    elif case == "one_request":                # R == 1
+        req = FakeReq(3)
+        slots = [[(req, -inf, inf)], [(req, 1.0, 2.0)]]
+        aids, speeds = [1, 0, 1, 1, 0, 0, 1], [1.5, 0, 0.5, 1.2, 2, 2, 1.9]
+    elif case == "at_kcap":                    # K == the candidate cap
+        reqs = [FakeReq(1) for _ in range(40)]
+        slots = [[(r, -inf, inf) for r in reqs], [(reqs[5], -inf, inf)]]
+        aids, speeds = [0] * 30 + [1] + [0] * 19, [1.0] * 50
+    elif case == "all_ineligible":             # no row has a live slot
+        reqs = [FakeReq(4), FakeReq(4)]
+        slots = [[(reqs[0], 0.0, 1.0), (reqs[1], 0.5, 1.0)], []]
+        aids, speeds = [0, 1, 0, 1, 0], [2.0, 0.7, 1.0, 2.5, 1.5]
+    elif case == "fills_mid_segment":          # A, then B fill: 3 passes
+        reqs = [FakeReq(2), FakeReq(3), FakeReq(10)]
+        slots = [[(r, -inf, inf) for r in reqs]]
+        aids, speeds = [0] * 12, [1.0] * 12
+    state = MatchState.from_scheduler(FakeSched(slots), token=("t",), kcap=32)
+    return state, np.asarray(aids), np.asarray(speeds, dtype=float)
+
+
+EDGE_CASES = ["one_row", "pow2_rows", "one_request", "at_kcap",
+              "all_ineligible", "fills_mid_segment"]
+
+
 @pytest.mark.parametrize("use_kernel", [False, True])
-@pytest.mark.parametrize("seed", [0, 3, 11, 29])
-def test_jax_backend_equals_oracle(seed, use_kernel):
-    rng = np.random.default_rng(seed)
-    state = _random_state(rng)
-    aids, speeds = _random_segment(rng, state, 50)
-    if aids is None:
-        return
+@pytest.mark.parametrize("case", [0, 3, 11, 29] + EDGE_CASES)
+def test_jax_backend_equals_oracle(case, use_kernel):
+    if isinstance(case, int):                  # a random state and segment
+        rng = np.random.default_rng(case)
+        state = _random_state(rng)
+        aids, speeds = _random_segment(rng, state, 50)
+        if aids is None:
+            return
+    else:
+        state, aids, speeds = _edge_segment(case)
     ref = match_chunk_seq(aids, speeds, state)
     got = match_chunk_jax(aids, speeds, state, use_kernel=use_kernel)
     assert np.array_equal(ref.choice, got.choice)
     assert np.array_equal(ref.granted, got.granted)
+    if case == "at_kcap":
+        assert state.cand_req.shape[1] == state.kcap == 32
+    if case == "all_ineligible":
+        assert (got.choice == -1).all()
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_jax_output_buffer_carries_iteration_count(use_kernel):
+    from repro import obs
+    from repro.accel._jax_impl import _match_jax
+    from repro.accel.engine import _pack_jax
+    state, aids, speeds = _edge_segment("fills_mid_segment")
+    iters = []
+    for match in (match_chunk, functools.partial(match_chunk_jax,
+                                                  use_kernel=use_kernel)):
+        with obs.session(tracing=False, metrics=True) as (_, reg):
+            match(aids, speeds, state)
+            hist = reg.get("accel.fixedpoint_iters")
+            assert hist.count == 1 and hist.vmin == hist.vmax
+            iters.append(hist.vmin)
+    buf, np_pad, kp = _pack_jax(aids, speeds, state)
+    out = np.asarray(_match_jax(buf, np_pad, kp, use_kernel=use_kernel))
+    assert out.shape == (2 * np_pad + 1,)
+    # NumPy's fixed point, the histogram and the buffer's last slot agree
+    assert iters == [out[-1], out[-1]] and out[-1] == 3
 
 
 def test_masked_first_fit_kernel_matches_ref():

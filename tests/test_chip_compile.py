@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.accel import _jax_impl
+from repro.accel.engine import SEG_ROWS
 from repro.accel.kernels import masked_first_fit, schedule_match, segmented_rank
 
 
@@ -70,17 +71,24 @@ def test_segmented_rank_compiles_for_v5e(one_chip, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_match_jax_with_kernel_compiles_for_v5e(one_chip, monkeypatch):
+def _compile_match_jax(one_chip, monkeypatch, n, k, r):
     # the fixed point calls the kernel with the platform's interpret
     # default; in this CPU-backend process that would be the interpreter
     # (cleared traces on both sides keep the patched one private to this test)
     monkeypatch.setattr(schedule_match, "_default_interpret", lambda: False)
     jax.clear_caches()
-    n, k, r = 16384, 32, 256
     try:
         compiled = _jax_impl._match_jax.lower(
-            _spec((n, k), one_chip), _spec((n, k), one_chip, jnp.bool_),
-            _spec((r,), one_chip), use_kernel=True).compile()
+            _spec((n * k + r,), one_chip), n, k, use_kernel=True).compile()
     finally:
         jax.clear_caches()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_match_jax_with_kernel_compiles_for_v5e(one_chip, monkeypatch):
+    # the one packed input buffer of a full segment at the default cap
+    _compile_match_jax(one_chip, monkeypatch, SEG_ROWS, 32, 256)
+
+
+def test_match_jax_with_kernel_compiles_for_v5e_wide_k(one_chip, monkeypatch):
+    _compile_match_jax(one_chip, monkeypatch, SEG_ROWS, 130, 256)

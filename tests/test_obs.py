@@ -126,6 +126,11 @@ def test_device_call_grant_and_scalar_spans_nest():
     put_bytes = sum(e["args"]["bytes"] for e in by["accel.jax.put"])
     assert counters["accel.jax_calls"].value == calls
     assert counters["accel.h2d_bytes"].value == put_bytes > 0
+    # one copy in, one copy out: the packed buffer of each call
+    assert counters["accel.host_copies"].value == 2 * calls
+    packed = sum(4 * (p["args"]["n"] * p["args"]["k"] + p["args"]["r"])
+                 for p in by["accel.jax.pack"])
+    assert counters["accel.h2d_bytes"].value == packed
     # the device path feeds the fixed point's iteration histogram
     assert counters["accel.fixedpoint_iters"].count == calls
     assert counters["accel.fixedpoint_iters"].vmin >= 1

@@ -24,6 +24,11 @@ The scheduler (VENN-SCHED) recomputes the plan lazily at the first
 check-in after any request arrival or completion, and at a check-in of an
 atom its plan does not cover:
 
+* atoms: a check-in's atom is the set of requirement classes it
+  satisfies, coded as an integer whose bit b is the b-th class in the order
+  first asked, exact for any number of classes; atoms are interned to ids
+  in first-seen order, the new atoms of one classification in ascending
+  code order;
 * supply: per-atom check-in counts over a trailing 24 h window of 60 s
   buckets, each check-in counted under the atom it fell in when the stream
   reached it; the rate is count / min(window, max(now - first check-in,
@@ -49,7 +54,6 @@ import random
 import numpy as np
 
 ARRIVAL, RESPONSE, DEADLINE = 0, 1, 2
-MAX_CLASSES = 6         # an atom's code (one bit per class) stays under 64
 
 
 class _Req:
@@ -89,10 +93,10 @@ class _Group:
     def __init__(self, name):
         self.name = name
         self.jobs = []
-        self.elig = set()           # atom codes
-        self.rates = {}             # code -> rate, ascending atom id
+        self.elig = set()           # atom ids
+        self.rates = {}             # atom id -> rate, ascending atom id
         self.supply = 0.0
-        self.alloc = {}             # code -> rate, insertion order
+        self.alloc = {}             # atom id -> rate, insertion order
 
     def pending(self):
         return [j for j in self.jobs
@@ -139,18 +143,22 @@ class Reference:
                             .astype(np.float64)) for c in ep["chunks"]]
         # scheduler state
         self.names = []             # requirement names, in order first asked
+        self.bit = {}               # name -> its bit in an atom's code
         self.version = 0
         self.groups = {}            # name -> _Group, in order first asked
         self.atom_id = {}           # code -> interned id (first-seen order)
+        self.codes = []             # interned id -> code
         self.dirty = True
-        self.covered = set()        # atom codes the plan covers
-        self.slots = {}             # code -> [[req, lo, hi], ...]
+        self.covered = set()        # atom ids the plan covers
+        self.slots = {}             # atom id -> [[req, lo, hi], ...]
+        self.live = None            # _live_atoms() until the next change
         self.tier = {}              # id(req) -> (lo, hi) of tiered requests
         self.decided = {}           # job id -> (round, attempt) decided
-        # supply: absorbed counts per (bucket, code); first check-in time
-        self.counts = {}            # bucket -> {code: count}
-        self.totals = {}            # code -> count inside the window
+        # supply: absorbed counts per (bucket, atom); first check-in time
+        self.counts = {}            # bucket -> {atom id: count}
+        self.totals = {}            # atom id -> count inside the window
         self.t0 = None
+        self.abs_ci, self.abs_row = 0, 0    # next check-in to absorb
         # simulation state
         self.heap = []
         self.seq = 0
@@ -161,37 +169,58 @@ class Reference:
 
     # ------------------------------------------------------------ stream
 
-    def _code_of(self, cpu, mem):
-        code = np.zeros(len(cpu), dtype=np.int64)
+    def _intern(self, code):
+        if code not in self.atom_id:
+            self.atom_id[code] = len(self.codes)
+            self.codes.append(code)
+            self.live = None
+        return self.atom_id[code]
+
+    def _atoms_of(self, cpu, mem):
+        """Interned atom ids of the check-ins ``cpu``, ``mem``: the classes
+        each satisfies as a bitmask, packed into little-endian 64-bit words
+        (one word up to 64 classes), new codes interned ascending."""
+        n, R = len(cpu), len(self.names)
+        if R == 0:
+            return np.full(n, self._intern(0), dtype=np.int64)
+        sat = np.ones((n, R), dtype=bool)
         for b, name in enumerate(self.names):
             m = self.req_mins[name]
-            ok = np.ones(len(cpu), dtype=bool)
             for cap, arr in (("cpu", cpu), ("mem", mem)):
                 if cap in m:
-                    ok &= arr >= m[cap]
-            code |= ok.astype(np.int64) << b
-        return code
+                    sat[:, b] &= arr >= m[cap]
+        packed = np.packbits(sat, axis=1, bitorder="little")
+        words = np.pad(packed, ((0, 0), (0, -packed.shape[1] % 8))) \
+            .view("<u8")
+        if words.shape[1] == 1:
+            uniq, inverse = np.unique(words[:, 0], return_inverse=True)
+            codes = uniq.tolist()
+        else:
+            uniq, inverse = np.unique(words, axis=0, return_inverse=True)
+            codes = [sum(w << (64 * k) for k, w in enumerate(row))
+                     for row in uniq.tolist()]
+        for c in sorted(codes):
+            self._intern(c)
+        lut = np.array([self.atom_id[c] for c in codes], dtype=np.int64)
+        return lut[inverse.reshape(-1)]
 
     def _classify(self, ci, start):
-        """Atom codes of chunk ``ci`` from row ``start``, interning new atoms
-        in ascending code order (the order ids are handed out in)."""
+        """Atom ids of chunk ``ci`` from row ``start``."""
         ck = self.chunks[ci]
-        codes = self._code_of(ck["cpu"][start:], ck["mem"][start:])
-        if not self.names or len(codes) == 0:
-            self.atom_id.setdefault(0, len(self.atom_id))
-        for c in np.unique(codes).tolist():
-            self.atom_id.setdefault(c, len(self.atom_id))
-        if "codes" not in ck:
-            ck["codes"] = codes
+        if not self.names or len(ck["cpu"]) == start:
+            self._intern(0)
+        atoms = self._atoms_of(ck["cpu"][start:], ck["mem"][start:])
+        if "atoms" not in ck:
+            ck["atoms"] = atoms
         else:
-            ck["codes"][start:] = codes
+            ck["atoms"][start:] = atoms
         ck["version"] = self.version
 
     def _absorb(self, now):
-        """Count every check-in up to ``now`` under its current code."""
+        """Count every check-in up to ``now`` under its current atom."""
         while self.abs_ci < len(self.chunks):
             ck = self.chunks[self.abs_ci]
-            if "codes" not in ck:
+            if "atoms" not in ck:
                 return
             t = ck["times"]
             hi = int(np.searchsorted(t, now, side="right"))
@@ -200,13 +229,14 @@ class Reference:
                 if self.t0 is None:
                     self.t0 = float(t[lo])
                 b = (t[lo:hi] // self.bucket).astype(np.int64)
-                key = (b << MAX_CLASSES) + ck["codes"][lo:hi]
+                radix = len(self.codes)
+                key = b * radix + ck["atoms"][lo:hi]
                 u, n = np.unique(key, return_counts=True)
-                mask = (1 << MAX_CLASSES) - 1
                 for k, c in zip(u.tolist(), n.tolist()):
-                    bc = self.counts.setdefault(k >> MAX_CLASSES, {})
-                    bc[k & mask] = bc.get(k & mask, 0) + c
-                    self.totals[k & mask] = self.totals.get(k & mask, 0) + c
+                    bk, a = divmod(k, radix)
+                    bc = self.counts.setdefault(bk, {})
+                    bc[a] = bc.get(a, 0) + c
+                    self.totals[a] = self.totals.get(a, 0) + c
                 self.abs_row = hi
             if hi < len(t):
                 return
@@ -214,7 +244,7 @@ class Reference:
             self.abs_row = 0
 
     def _rates(self, now):
-        """Per-code rates over the trailing window (codes with traffic)."""
+        """Per-atom rates over the trailing window (atoms with traffic)."""
         horizon = int(math.ceil((now - self.window) / self.bucket))
         for b in sorted(b for b in self.counts if b < horizon):
             for c, n in self.counts.pop(b).items():
@@ -225,14 +255,15 @@ class Reference:
 
     # ------------------------------------------------------------ replan
 
-    def _has(self, code, name):
-        return (code >> self.names.index(name)) & 1
+    def _has(self, atom, name):
+        return (self.codes[atom] >> self.bit[name]) & 1
 
     def _replan(self, now):
         self.dirty = False
+        self.live = None
         self._absorb(now)
         rates = self._rates(now)
-        seen = sorted(rates, key=self.atom_id.__getitem__)
+        seen = sorted(rates)
         active = [g for g in self.groups.values() if g.pending()]
         for g in active:
             g.rates = {c: rates[c] for c in seen if self._has(c, g.name)}
@@ -341,9 +372,8 @@ class Reference:
         job.current = req
         self.open += 1
         if job.cls not in self.groups:
-            if len(self.names) == MAX_CLASSES:
-                raise ValueError(f"more than {MAX_CLASSES} requirement classes")
             self.groups[job.cls] = _Group(job.cls)
+            self.bit[job.cls] = len(self.names)
             self.names.append(job.cls)
             self.version += 1
         g = self.groups[job.cls]
@@ -413,6 +443,7 @@ class Reference:
         filled = req.granted >= req.demand
         if filled:
             self.open -= 1
+            self.live = None
         rt = job.task_mean / (speed if speed > 1e-3 else 1e-3) \
             * math.exp(job.task_sigma * z)
         ok = not (u < self.fail_base + self.fail_boost / (1.0 + speed))
@@ -424,19 +455,21 @@ class Reference:
 
     # ------------------------------------------------------------ decide
 
-    def _live_codes(self):
-        """Codes whose check-ins need a decision: uncovered atoms (the plan
-        is recomputed there) and atoms with an unfilled candidate."""
-        live = np.ones(1 << MAX_CLASSES, dtype=bool)
-        for c in self.covered:
-            live[c] = any(s[0].demand > s[0].granted for s in self.slots[c])
-        return live
+    def _live_atoms(self):
+        """Atom ids whose check-ins need a decision: uncovered atoms (the
+        plan is recomputed there) and atoms with an unfilled candidate.
+        Kept until a replan, a fill or a new atom changes it."""
+        if self.live is None:
+            self.live = np.ones(len(self.codes), dtype=bool)
+            for a in self.covered:
+                self.live[a] = any(s[0].demand > s[0].granted
+                                   for s in self.slots[a])
+        return self.live
 
     def run(self, horizon: float) -> dict:
         for j in self.jobs:
             self._push(j.arrival, ARRIVAL, j)
         self.ci, self.row = 0, 0
-        self.abs_ci, self.abs_row = 0, 0
         if self.chunks:
             self._classify(0, 0)
         n_jobs = len(self.jobs)
@@ -474,22 +507,22 @@ class Reference:
             if not self.open:
                 self.row = stop
                 continue
-            codes, speed = ck["codes"], ck["speed"]
+            atoms, speed = ck["atoms"], ck["speed"]
             if self.dirty:
                 idx = [self.row]
             else:
-                idx = (np.flatnonzero(self._live_codes()[codes[self.row:stop]])
+                idx = (np.flatnonzero(self._live_atoms()[atoms[self.row:stop]])
                        + self.row).tolist()
             self.row = stop
             for i in idx:
                 t = float(times[i])
-                code = int(codes[i])
+                atom = int(atoms[i])
                 s = float(speed[i])
-                replan = self.dirty or code not in self.covered
+                replan = self.dirty or atom not in self.covered
                 if replan:
                     self._replan(t)
                 req = None
-                for r, lo, hi in self.slots.get(code, ()):
+                for r, lo, hi in self.slots.get(atom, ()):
                     if r.demand > r.granted and lo <= s < hi:
                         req = r
                         break

@@ -10,7 +10,9 @@ Usage::
 A cell of ``BENCHMARK.json`` names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); its per-layer metrics are read by
-``bench/metrics/<name>.py``.  All three are found by name, so a cell, a
+``bench/metrics/<name>.py``, whose ``read(ctx)`` takes spans, the device
+trace, totals of the window and the program's counters
+(``ctx["counters"]``).  All three are found by name, so a cell, a
 configuration or a metric is added by adding files and entries.
 
 Set-up (``setup_s``): JAX's persistent compilation cache inside the
@@ -277,6 +279,7 @@ def _run(cell, seed, seconds, trace, root, devs, clog, overrides, t_start,
             "stream_s": tracing.stream_s, "compiles": window_compiles,
             "spans": tracing.spans, "traced_s": tracing.traced_s,
             "trace": red, "calls": tracing.calls,
+            "counters": tracing.counters,
             "device_kind": device["kind"],
         }
         line["metrics"] = {}
@@ -299,10 +302,11 @@ def _run(cell, seed, seconds, trace, root, devs, clog, overrides, t_start,
 
 class Tracing:
     """The traced run's instruments.  Over the whole window: the
-    ``repro.obs`` counters (``sim.stream_wall_s``).  Over its first
-    :data:`PROFILE_SECONDS`: ``repro.obs`` spans, the JAX profiler (each
-    batch inside an annotation, its start noted on the host clock), and the
-    live rows and candidate slots of every device call."""
+    ``repro.obs`` counters, every one read once the window has closed
+    (``counters``: name -> value; ``stream_s``: ``sim.stream_wall_s``).
+    Over its first :data:`PROFILE_SECONDS`: ``repro.obs`` spans, the JAX
+    profiler (each batch inside an annotation, its start noted on the host
+    clock), and the live rows and candidate slots of every device call."""
 
     def __init__(self):
         self.calls, self.marks = [], []
@@ -361,7 +365,10 @@ class Tracing:
         from repro import obs
         if self.active:
             self._end_profile(time.perf_counter())
-        self.stream_s = self.registry.counter("sim.stream_wall_s").value
+        self.counters = {m["name"]: m["value"]
+                         for m in self.registry.snapshot()
+                         if m["kind"] == "counter"}
+        self.stream_s = self.counters.get("sim.stream_wall_s", 0.0)
         obs.disable()
 
     def reduce(self, platform: str) -> dict:

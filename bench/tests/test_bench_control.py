@@ -1,7 +1,9 @@
 """The comparison that decides ``correct`` separates the program from its
 control at a size a test run holds: the timed path (here on the CPU's
 backend) reads 0 on every number for every seed, and the reference run in
-float32 speeds, the precision below the configuration's, fails."""
+float32 speeds, the precision below the configuration's, fails.  Each cell
+of ``BENCHMARK.json`` runs at the sizes of its traffic file's ``control``
+block."""
 import sys
 from pathlib import Path
 
@@ -12,18 +14,14 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 from bench import compare, control      # noqa: E402
+from bench.tests.test_bench_rehearsal import traffic_blocks  # noqa: E402
 
-SMALL = {
-    "biased_hp.r500": {"base_rate": 40, "num_jobs": 20,
-                       "mean_interarrival_s": 30, "episode_sim_s": 900},
-    "even4.r500": {"base_rate": 40, "num_jobs": 20, "mean_interarrival_s": 30,
-                   "episode_sim_s": 900},
-    "even4.r2": {"base_rate": 2, "num_jobs": 6, "episode_sim_s": 21600},
-}
+SMALL = traffic_blocks("control")
 
 
 @pytest.mark.parametrize("cell", sorted(SMALL))
 def test_program_reads_zero_and_control_fails(cell):
+    assert SMALL[cell], f"{cell}: no control block in its traffic file"
     for seed in (1, 2, 3**20):
         r = control.readings(cell, seed, overrides=SMALL[cell])
         assert r["rounds"] > 0

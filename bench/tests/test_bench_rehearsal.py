@@ -3,9 +3,10 @@
 The harness's look for a chip is skipped (``require_tpu=False``) and the
 engine is steered onto the device path (the jitted fixed point with the
 Pallas kernel in interpret mode) by patching ``platform_backend``, as
-``tests/test_chip_smoke.py`` does.  Each cell's traffic is scaled down in
-the test; the rest of a run, the comparison with the reference included,
-runs as on the chip.  Broken timed paths must come out not correct."""
+``tests/test_chip_smoke.py`` does.  Each cell of ``BENCHMARK.json`` runs its
+traffic at the tiny sizes of the traffic file's ``rehearsal`` block; the
+rest of a run, the comparison with the reference included, runs as on the
+chip.  Broken timed paths must come out not correct."""
 import json
 import sys
 from pathlib import Path
@@ -17,18 +18,19 @@ ROOT = Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import run, system               # noqa: E402,F401 (program path)
+from bench import run, system, workload     # noqa: E402,F401 (program path)
 import repro.accel.engine as engine_mod      # noqa: E402
 
-TINY = {
-    "biased_hp.r500": {"base_rate": 30, "num_jobs": 16,
-                       "mean_interarrival_s": 20, "episode_sim_s": 400,
-                       "batch_sim_s": 10},
-    "even4.r500": {"base_rate": 30, "num_jobs": 16, "mean_interarrival_s": 20,
-                   "episode_sim_s": 400, "batch_sim_s": 10},
-    "even4.r2": {"base_rate": 2, "num_jobs": 4, "mean_interarrival_s": 600,
-                 "episode_sim_s": 3600, "batch_sim_s": 60},
-}
+
+def traffic_blocks(kind: str, root: Path = ROOT) -> dict:
+    """Each cell of ``BENCHMARK.json`` -> the ``kind`` block (``rehearsal``
+    or ``control``) of its traffic file, None where the file has none."""
+    return {w["name"]: workload.load_json("traffic", w["traffic"],
+                                          root / "bench").get(kind)
+            for w in run.load_benchmark(root)["workloads"]}
+
+
+TINY = traffic_blocks("rehearsal")
 SEED = 2**31 + 4242
 
 
@@ -37,6 +39,7 @@ def _quiet(*args, **kwargs):
 
 
 def _run(cell, trace=False, seconds=0.5, seed=SEED):
+    assert TINY[cell], f"{cell}: no rehearsal block in its traffic file"
     return run.run_cell(cell, seed, seconds, trace, require_tpu=False,
                         traffic_overrides=TINY[cell], log=_quiet)
 
@@ -47,8 +50,13 @@ def device_path(monkeypatch):
 
 
 def test_tiny_sizes_cover_every_cell():
-    cells = [w["name"] for w in run.load_benchmark()["workloads"]]
-    assert sorted(cells) == sorted(TINY)
+    """Every cell's traffic file has a ``rehearsal`` block that overrides
+    parameters the file itself sets."""
+    for w in run.load_benchmark()["workloads"]:
+        tr = workload.load_json("traffic", w["traffic"])
+        sizes = tr.get("rehearsal")
+        assert isinstance(sizes, dict) and sizes, w["name"]
+        assert set(sizes) <= set(tr) - {"rehearsal", "control"}, w["name"]
 
 
 @pytest.mark.parametrize("cell", sorted(TINY))

@@ -200,7 +200,7 @@ def _replan_breakdown_row(seed: int = 1):
         sim.run()
         wall = time.time() - t0
         stats = span_stats(tr.events)
-        hist = reg.get("venn.replan_wall_s")
+        hist = reg.get("venn.replan_latency_s")
     replan = stats.get("venn.replan", {"count": 0, "total_us": 0.0})
     total_s = replan["total_us"] / 1e6
     phases_s = {

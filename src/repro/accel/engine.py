@@ -509,9 +509,14 @@ class ArrayMatchEngine:
             tr = _obstrace.TRACER
             if tr.enabled:
                 tr.instant("accel.expand", cat="accel", kcap=st.kcap)
+            reg = _obsmetrics.REGISTRY
+            if reg.enabled:
+                reg.counter("accel.expansions").inc()
             if not st.expand():
                 # the stored rows themselves were export-capped prefixes:
                 # widen the cap and have the caller rebuild + re-match
+                if reg.enabled:
+                    reg.counter("accel.wider_exports").inc()
                 self.kcap = max(self.kcap * 2, st.kcap * 2)
                 self.state = None
                 raise NeedWiderExport

@@ -139,12 +139,21 @@ class EligibilityIndex:
                 key = frozenset(nm for b, nm in enumerate(names) if code >> b & 1)
                 lut[u] = self.intern(key)
         else:
-            packed = np.packbits(sat, axis=1)
-            uniq, inverse = np.unique(packed, axis=0, return_inverse=True)
+            # the code as little-endian 64-bit words (bit b = class b, as
+            # above), most significant word first: unique rows then come
+            # out in ascending code order, the order new codes are interned
+            # in by the branches above
+            packed = np.packbits(sat, axis=1, bitorder="little")
+            words = np.pad(packed, ((0, 0), (0, -packed.shape[1] % 8))) \
+                .view("<u8")[:, ::-1]
+            uniq, inverse = np.unique(words, axis=0, return_inverse=True)
             lut = np.empty(len(uniq), dtype=np.int64)
-            for u in range(len(uniq)):
-                bits = np.unpackbits(uniq[u])[:R]
-                lut[u] = self.intern(frozenset(nm for nm, b in zip(names, bits) if b))
+            for u, row in enumerate(uniq.tolist()):
+                code = 0
+                for w in row:
+                    code = code << 64 | w
+                key = frozenset(nm for b, nm in enumerate(names) if code >> b & 1)
+                lut[u] = self.intern(key)
         return lut[inverse.ravel()]
 
     def eligible_atoms(self, requirement: Requirement, atoms: Iterable[AtomKey]) -> FrozenSet[AtomKey]:

@@ -503,9 +503,14 @@ class VennScheduler(BaseScheduler):
         if tok is not None:
             tr.end(tok, jobs=num_jobs, groups=len(active_groups))
         if reg.enabled:
+            dt = time.perf_counter() - t_replan
             reg.counter("venn.replans").inc()
-            reg.histogram("venn.replan_wall_s", lo=1e-7, hi=1e2).record(
-                time.perf_counter() - t_replan)
+            reg.counter("venn.replan_wall_s").inc(dt)
+            reg.histogram("venn.replan_latency_s", lo=1e-7, hi=1e2).record(dt)
+            # the scale each replan works at: groups with pending jobs and
+            # atoms the supply window has seen, summed over replans
+            reg.counter("venn.replan_groups").inc(len(active_groups))
+            reg.counter("venn.replan_atoms").inc(len(atoms))
             if eng is not None:
                 # incremental-reuse telemetry: how much of this replan was
                 # served from caches vs recomputed (order/lowered/merged)

@@ -274,7 +274,7 @@ class Simulator:
             # the atom partition only refines inside on_request (a heap
             # event), so one version check per drain segment suffices
             if index.version != self._chunk_version:
-                self._classify_chunk(self._chunk, self._cursor)
+                self._reclassify()
             times, cpu, mem = self._times, self._cpu, self._mem
             spd, aids = self._speed, self._aids
             n_times = len(times)
@@ -378,7 +378,7 @@ class Simulator:
             if self._chunk is None:
                 return False
             if index.version != self._chunk_version:
-                self._classify_chunk(self._chunk, self._cursor)
+                self._reclassify()
             times = self._times
             cursor = self._cursor
             if cursor >= len(times):
@@ -665,6 +665,29 @@ class Simulator:
                 self._aids = ck.atom_ids
             self._cursor = 0
             return
+
+    def _reclassify(self) -> None:
+        """Re-classify the unconsumed tail of the current chunk after the
+        requirement set grew (an ``atom_version`` bump): the ``sim.reclassify``
+        span and counters.  A chunk's first classification is counted under
+        ``sim.chunk_load`` instead."""
+        tr = _obstrace.TRACER
+        reg = _obsmetrics.REGISTRY
+        if not (tr.enabled or reg.enabled):
+            self._classify_chunk(self._chunk, self._cursor)
+            return
+        rows = self._chunk.n - self._cursor
+        tok = tr.begin("sim.reclassify", cat="sim") if tr.enabled else None
+        t0 = time.perf_counter()
+        self._classify_chunk(self._chunk, self._cursor)
+        dt = time.perf_counter() - t0
+        if reg.enabled:
+            reg.counter("sim.reclassify_wall_s").inc(dt)
+            reg.counter("sim.reclassify_rows").inc(rows)
+            reg.counter("sim.reclassifies").inc()
+        if tok is not None:
+            tr.end(tok, rows=rows,
+                   classes=len(self.sched.index.requirements))
 
     def _classify_chunk(self, ck: DeviceChunk, start: int) -> None:
         ids = self.sched.classify_caps({"cpu": ck.cpu[start:],

@@ -23,6 +23,8 @@ Every scheduler implements the same interface the simulator drives:
 plus the vectorized check-in fast path shared by every scheduler:
 
     classify_caps(caps)        — struct-of-arrays chunk -> interned atom ids
+    refine_caps(ids, caps, k)  — fold requirements added after the first k
+                                 into ids classified under those k
     begin_chunk(times, ids)    — hand the chunk to the scheduler (supply feed)
     checkin(atom_id, ...)      — O(1) assignment by interned atom id
 
@@ -97,6 +99,12 @@ class BaseScheduler:
 
     def classify_caps(self, caps: Dict[str, np.ndarray]) -> np.ndarray:
         return self.index.classify(caps)
+
+    def refine_caps(self, ids: np.ndarray, caps: Dict[str, np.ndarray],
+                    since: int) -> np.ndarray:
+        """``classify_caps`` for rows whose ``ids`` were classified under
+        the first ``since`` requirements."""
+        return self.index.refine(ids, caps, since)
 
     def begin_chunk(self, times: np.ndarray, atom_ids: np.ndarray) -> None:
         """A new check-in chunk starts — baselines keep no supply state."""

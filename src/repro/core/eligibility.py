@@ -11,8 +11,10 @@ Fast path: every realized atom is **interned** to a dense int id, and the
 requirement thresholds are kept as a ``(R, C)`` min-threshold matrix so that
 classifying a whole chunk of devices is one NumPy broadcast comparison
 (``caps[:, None, :] >= mins[None, :, :]``) instead of per-device Python
-generator calls.  Frozenset keys remain the boundary representation (plans,
-supply estimation, tests); ids are what the per-check-in hot path touches.
+generator calls.  A requirement added later is folded into ids already
+classified (``refine``: one new bit per row) instead of classifying again.
+Frozenset keys remain the boundary representation (plans, supply estimation,
+tests); ids are what the per-check-in hot path touches.
 """
 from __future__ import annotations
 
@@ -155,6 +157,63 @@ class EligibilityIndex:
                 key = frozenset(nm for b, nm in enumerate(names) if code >> b & 1)
                 lut[u] = self.intern(key)
         return lut[inverse.ravel()]
+
+    def refine(self, ids: np.ndarray, caps: Dict[str, np.ndarray],
+               since: int) -> np.ndarray:
+        """Fold the requirements added after the first ``since`` into atom
+        ids that :meth:`classify` gave ``caps`` under those ``since``.
+
+        Returns what :meth:`classify` would return now, with the same atoms
+        interned in the same order, at O(n) per new requirement: an atom's
+        key already holds its first ``since`` bits, so each new bit is one
+        compare per capability column, appended to the id and densely
+        relabelled through a table over ``2 * atoms``.  The new keys are
+        interned in ascending code order (bit b = the b-th requirement), as
+        every :meth:`classify` branch interns them."""
+        reqs = self.requirements
+        n = len(ids)
+        if since >= len(reqs) or n == 0:
+            return ids
+        cols = [None if caps.get(c) is None
+                else np.asarray(caps[c], dtype=np.float64)
+                for c in self._cap_names]
+        then = {c for r in reqs[:since] for c, _ in r.mins}
+        if any(col is not None and c not in then and np.isnan(col).any()
+               for c, col in zip(self._cap_names, cols)):
+            # a NaN in a column new since ``since`` clears every bit of its
+            # row in ``classify``, the old bits too
+            return self.classify(caps)
+        # ``cur`` indexes ``origin``: (atom id under ``since``, new bits)
+        origin = [(a, 0) for a in range(self.num_atoms)]
+        cur = ids
+        for b in range(len(reqs) - since):
+            sat = np.ones(n, dtype=bool)
+            for col, lo in zip(cols, self._mins[since + b]):
+                if col is not None:
+                    sat &= col >= lo
+                elif not 0.0 >= lo:
+                    sat[:] = False
+            comb = cur * 2 + sat
+            seen = np.zeros(2 * len(origin), dtype=bool)
+            seen[comb] = True
+            present = np.flatnonzero(seen).tolist()
+            cur = (np.cumsum(seen) - 1)[comb]
+            origin = [(origin[p >> 1][0], origin[p >> 1][1] | (p & 1) << b)
+                      for p in present]
+        pos = {r.name: i for i, r in enumerate(reqs)}
+        names = [r.name for r in reqs[since:]]
+        lut = np.empty(len(origin), dtype=np.int64)
+        fresh = []
+        for c, (a, bits) in enumerate(origin):
+            if bits:
+                code = sum(1 << pos[nm] for nm in self.key_of(a))
+                fresh.append((code | bits << since, c, a, bits))
+            else:
+                lut[c] = a
+        for _, c, a, bits in sorted(fresh):
+            lut[c] = self.intern(self.key_of(a) | frozenset(
+                nm for t, nm in enumerate(names) if bits >> t & 1))
+        return lut[cur]
 
     def eligible_atoms(self, requirement: Requirement, atoms: Iterable[AtomKey]) -> FrozenSet[AtomKey]:
         """Atoms whose devices satisfy ``requirement`` (atom contains req name)."""

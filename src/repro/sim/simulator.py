@@ -141,6 +141,8 @@ class Simulator:
         #                               check-in matching loop, per engine)
         self.stream_seconds = 0.0     # wall time producing + classifying
         #                               chunks (shared, engine-independent)
+        self._chunk_reqs: Optional[int] = None  # requirements the current
+        #                               chunk's atom ids were classified under
 
     # ------------------------------------------------------------------ api
 
@@ -679,19 +681,32 @@ class Simulator:
         rows = self._chunk.n - self._cursor
         tok = tr.begin("sim.reclassify", cat="sim") if tr.enabled else None
         t0 = time.perf_counter()
-        self._classify_chunk(self._chunk, self._cursor)
+        folded = self._classify_chunk(self._chunk, self._cursor)
         dt = time.perf_counter() - t0
         if reg.enabled:
             reg.counter("sim.reclassify_wall_s").inc(dt)
             reg.counter("sim.reclassify_rows").inc(rows)
             reg.counter("sim.reclassifies").inc()
+            if folded:
+                reg.counter("sim.reclassify_folded").inc()
+                reg.counter("sim.reclassify_bits").inc(folded)
         if tok is not None:
             tr.end(tok, rows=rows,
                    classes=len(self.sched.index.requirements))
 
-    def _classify_chunk(self, ck: DeviceChunk, start: int) -> None:
-        ids = self.sched.classify_caps({"cpu": ck.cpu[start:],
-                                        "mem": ck.mem[start:]})
+    def _classify_chunk(self, ck: DeviceChunk, start: int) -> int:
+        """Classify ``ck`` from row ``start`` on.  Returns the number of
+        requirements folded into the rows' existing ids, 0 when they were
+        classified in full (a chunk's first classification)."""
+        caps = {"cpu": ck.cpu[start:], "mem": ck.mem[start:]}
+        since = self._chunk_reqs
+        reqs = len(self.sched.index.requirements)
+        if ck.atom_ids is None or since is None:
+            ids = self.sched.classify_caps(caps)
+            since = reqs
+        else:
+            ids = self.sched.refine_caps(ck.atom_ids[start:], caps, since)
+        self._chunk_reqs = reqs
         if ck.atom_ids is None:
             ck.atom_ids = ids           # initial classification at chunk load
         else:
@@ -703,6 +718,7 @@ class Simulator:
             if type(self._aids) is list:        # array mode aliases the
                 self._aids[start:] = ids.tolist()   # chunk array directly
         self._chunk_version = self.sched.atom_version
+        return reqs - since
 
     def _skip_idle(self, until: float) -> None:
         """Fast-forward the device cursor while no request is outstanding.

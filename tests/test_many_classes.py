@@ -5,12 +5,17 @@ classes):
   ascending code order (bit b = the b-th class asked), the order of its
   narrower branches and of the plain reference ``bench/reference.py``, and
   the program agrees with the reference on a grid that asks all 64 classes;
+* ``EligibilityIndex.refine`` folds new classes into a tail's atom ids and
+  gives the ids and interning order of a full ``classify``, at every class
+  count up to 70, in both drains and across a snapshot and restore;
 * the ``sim.reclassify`` span and counters count every re-classification
-  of a chunk's tail and its rows; the replan and expansion counters sum
+  of a chunk's tail and its rows, and every one is a fold; the replan and
+  expansion counters sum
   what their spans and the engine report; with the registry off none of
   them is touched;
 * the ``reclassify_share`` and ``replan_share`` readers."""
 import math
+import pickle
 import sys
 from pathlib import Path
 from types import SimpleNamespace
@@ -32,12 +37,15 @@ from repro.accel.engine import (ArrayMatchEngine,              # noqa: E402
 import repro.accel.engine as engine_mod                        # noqa: E402
 from repro.core.eligibility import EligibilityIndex            # noqa: E402
 from repro.core.types import Requirement                       # noqa: E402
+from repro.faults import restore_simulator, snapshot_simulator  # noqa: E402
 from repro.obs import metrics as obsmetrics                    # noqa: E402
+from repro.sim.simulator import Simulator                      # noqa: E402
 
 NEW_COUNTERS = ("sim.reclassify_wall_s", "sim.reclassify_rows",
                 "sim.reclassifies", "venn.replan_wall_s",
                 "venn.replan_groups", "venn.replan_atoms",
-                "accel.expansions", "accel.wider_exports")
+                "accel.expansions", "accel.wider_exports",
+                "sim.reclassify_folded", "sim.reclassify_bits")
 
 
 @pytest.fixture(autouse=True)
@@ -76,6 +84,122 @@ def test_classify_interns_ascending_codes_past_63_classes(R):
     assert codes == ref.codes
 
 
+# ------------------------------------------------------ folding new classes
+
+def _asked_requirements(R):
+    """``_hand_built(R)``'s classes in the order they are asked, and its
+    chunk's capabilities."""
+    ep, _ = _hand_built(R)
+    mins = {r["name"]: r["mins"] for r in ep["requirements"]}
+    reqs = [Requirement.of(j["cls"], **mins[j["cls"]]) for j in ep["jobs"]]
+    ck = ep["chunks"][0]
+    return reqs, {"cpu": ck["cpu"], "mem": ck["mem"]}
+
+
+@pytest.mark.parametrize("step", [1, 3])
+@pytest.mark.parametrize("start", [0, 250])
+def test_refine_equals_classify(step, start):
+    """Fold ``step`` classes at a time into the tail from row ``start`` on,
+    up to 70 classes, crossing ``classify``'s R = 16/17 and 63/64 branches;
+    after each fold the ids and the interned keys, in order, are those of
+    an index that classifies the tail in full at every step."""
+    reqs, caps = _asked_requirements(70)
+    tail = {c: v[start:] for c, v in caps.items()}
+    fold, full = EligibilityIndex([]), EligibilityIndex([])
+    ids = fold.classify(caps)[start:]
+    full.classify(caps)
+    since = 0
+    while since < len(reqs):
+        R = min(since + step, len(reqs))
+        for r in reqs[since:R]:
+            fold.add_requirement(r)
+            full.add_requirement(r)
+        ids = fold.refine(ids, tail, since)
+        assert ids.tolist() == full.classify(tail).tolist(), R
+        assert fold.interner.keys() == full.interner.keys(), R
+        since = R
+    assert fold.num_atoms > 100
+
+
+def test_refine_clears_rows_with_nan_in_a_new_column():
+    """A NaN in a capability no earlier class named fails every class in
+    ``classify``, the earlier ones too; the fold agrees."""
+    caps = {"cpu": np.array([1.0, 3.0, 3.0, np.nan]),
+            "mem": np.array([np.nan, 1.0, np.nan, 2.0])}
+    fold, full = EligibilityIndex([]), EligibilityIndex([])
+    fold.add_requirement(Requirement.of("c", cpu=2.0))
+    full.add_requirement(Requirement.of("c", cpu=2.0))
+    ids = fold.classify(caps)
+    full.classify(caps)
+    for idx in (fold, full):
+        idx.add_requirement(Requirement.of("m", mem=1.0))
+    assert fold.refine(ids, caps, 1).tolist() == full.classify(caps).tolist()
+    assert fold.interner.keys() == full.interner.keys()
+
+
+def _spaced_episode(R=70):
+    """``_hand_built(R)`` with its classes asked one every 2 s, so each
+    re-classification folds one class into a tail that starts mid-chunk."""
+    ep, _ = _hand_built(R)
+    for k, job in enumerate(ep["jobs"]):
+        job["arrival"] = 1.0 + 2.0 * k
+    ep["traffic"] = {"episode_sim_s": 180.0}
+    return ep
+
+
+@pytest.mark.parametrize("engine", ["python", "array"])
+def test_drains_fold_as_classify_would(engine, monkeypatch):
+    """Every fold either drain makes gives the ids and interning order that
+    a full ``classify`` of the same tail gives on a copy of the index."""
+    checked = []
+    refine = EligibilityIndex.refine
+
+    def checked_refine(index, ids, caps, since):
+        twin = pickle.loads(pickle.dumps(index))
+        want = twin.classify(caps)
+        got = refine(index, ids, caps, since)
+        assert got.tolist() == want.tolist()
+        assert index.interner.keys() == twin.interner.keys()
+        checked.append((len(ids), len(index.requirements) - since))
+        return got
+
+    monkeypatch.setattr(EligibilityIndex, "refine", checked_refine)
+    system.make_simulator(_spaced_episode(), engine=engine).run()
+    assert len(checked) == 70
+    assert all(bits == 1 for _, bits in checked)
+    assert 0 < min(n for n, _ in checked) < max(n for n, _ in checked) < 600
+
+
+@pytest.mark.parametrize("engine", ["python", "array"])
+def test_restore_between_new_class_and_fold(engine, tmp_path, monkeypatch):
+    """A snapshot taken when a new class has bumped the atom version and
+    the tail is not yet re-classified restores to the grants of a run
+    never interrupted, and the restored run folds."""
+    ep = _spaced_episode()
+    whole = system.make_simulator(ep, engine=engine)
+    whole.run()
+
+    arrive = Simulator._on_job_arrival
+
+    def arrive_and_snapshot(sim, job):
+        arrive(sim, job)
+        if job.job_id == 30:
+            assert sim.sched.index.version != sim._chunk_version
+            snapshot_simulator(sim, str(tmp_path), 0)
+
+    monkeypatch.setattr(Simulator, "_on_job_arrival", arrive_and_snapshot)
+    system.make_simulator(ep, engine=engine).run()
+    monkeypatch.setattr(Simulator, "_on_job_arrival", arrive)
+    with obs.session(tracing=False, metrics=True) as (_, reg):
+        restored = restore_simulator(str(tmp_path))
+        restored.run()
+        c = {m["name"]: m["value"] for m in reg.snapshot()
+             if m["kind"] == "counter"}
+    assert restored.grant_log == whole.grant_log
+    assert len(whole.grant_log) > 0
+    assert c["sim.reclassify_folded"] == c["sim.reclassifies"] == 70 - 30
+
+
 @pytest.fixture
 def device_path(monkeypatch):
     monkeypatch.setattr(engine_mod, "platform_backend", lambda: ("jax", True))
@@ -98,6 +222,23 @@ def test_reference_agrees_with_program_asking_all_64_classes(device_path):
     assert len(ref.names) == 64
     assert len(want["rounds"]) > 0
     assert compare.numbers(ans, want) == dict.fromkeys(compare.LIMITS, 0)
+
+
+def test_every_reclassification_of_the_grid_folds():
+    """On the ``grid8x8`` episode every re-classification is a fold, of
+    one class each: the classes asked while a chunk was loaded."""
+    sizes = dict(traffic_blocks("rehearsal")["grid8x8.r500"], base_rate=6,
+                 num_jobs=64, mean_interarrival_s=4, episode_sim_s=300)
+    ep = workload.make_episode("grid8x8", "r500", 2**31 + 7, overrides=sizes)
+    with obs.session(tracing=False, metrics=True) as (_, reg):
+        sim = system.make_simulator(ep)
+        sim.run()
+        c = {m["name"]: m["value"] for m in reg.snapshot()
+             if m["kind"] == "counter"}
+    asked = len(sim.sched.index.requirements)
+    assert asked > 16
+    assert c["sim.reclassify_folded"] == c["sim.reclassifies"] == asked
+    assert c["sim.reclassify_bits"] == asked
 
 
 # ------------------------------------------------------ counters
